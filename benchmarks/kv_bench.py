@@ -15,22 +15,19 @@ Three lanes, all over the same smoke model:
     p99 of the gap between consecutive decode steps (what a decoding slot
     actually waits through) plus the deterministic worst-case prefill
     tokens a single tick can interpose.
-  * ``tp``     — the 1->N tensor-parallel scaling curve (ISSUE 8): the
-    paged engine re-run per mesh geometry in a fresh subprocess
-    (``scaling_child`` — XLA_FLAGS must precede backend init) on fake CPU
-    devices. Greedy tokens are asserted identical across geometries; tok/s
-    per tp rides the summary. On fake devices the curve measures GSPMD
-    partition overhead, not speedup — real scaling needs real chips.
+  * ``tp``     — the tensor-parallel curve: the paged engine re-run in
+    THIS process at tp = 1 and, when two or more devices are visible,
+    tp = 2 over the real devices (a child process could not reach a chip
+    this process already holds). Greedy tokens are asserted identical
+    across geometries; tok/s per tp rides the summary, named with the
+    device it ran on.
 
 ``REPRO_BENCH_TINY=1`` shrinks the workload and writes ``BENCH_kv.json``
 at the repo root (uploaded as a CI artifact).
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -38,10 +35,11 @@ import numpy as np
 
 from repro.config import get_smoke_config
 from repro.core.runtime import ModelRuntime
+from repro.distrib import serve_mesh
 from repro.serve.engine import PagedServeEngine, ServeEngine
 from repro.serve.kv import kv_page_bytes
 
-from .common import REPO_ROOT, emit, write_summary
+from .common import emit, mixed_workload, run_engine_timed, write_summary
 
 TINY = bool(os.environ.get("REPRO_BENCH_TINY"))
 
@@ -189,26 +187,26 @@ def _lane_hol(rt, summary):
         hol_tokens_chunked=res["chunked"]["hol_tokens"])
 
 
-def _lane_tp(summary):
-    """Paged decode under serve-time TP, one fresh process per geometry."""
+def _lane_tp(cfg, summary):
+    """Paged decode under serve-time TP, in this process, over the visible
+    devices (tp = 2 only where two are visible)."""
     n_req = 8 if TINY else 16
+    prompt_hi, new_hi = 24, 12
+    max_len = prompt_hi + new_hi + 8
+    wl = mixed_workload(n_req, prompt_hi, new_hi, seed=7)
     rows = []
-    for tp in (1, 2):
-        cmd = [sys.executable, "-m", "benchmarks.scaling_child",
-               "--tp", str(tp), "--n-req", str(n_req),
-               "--page-size", str(PAGE), "--prefill-chunk", str(CHUNK)]
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in (str(REPO_ROOT / "src"), env.get("PYTHONPATH")) if p)
-        proc = subprocess.run(cmd, capture_output=True, text=True,
-                              cwd=REPO_ROOT, env=env, timeout=900)
-        lines = [l for l in proc.stdout.splitlines()
-                 if l.startswith("RESULT ")]
-        if proc.returncode != 0 or not lines:
-            raise RuntimeError(
-                f"scaling child tp={tp} failed (rc={proc.returncode}):\n"
-                f"{proc.stdout[-2000:]}\n{proc.stderr[-2000:]}")
-        rows.append(json.loads(lines[-1][len("RESULT "):]))
+    for tp in sorted({1, min(2, jax.device_count())}):
+        rt = ModelRuntime(cfg, key=jax.random.PRNGKey(0),
+                          mesh=serve_mesh(tp) if tp > 1 else None)
+        make = lambda: PagedServeEngine(rt, max_batch=4, max_len=max_len,
+                                        eos_id=-1, page_size=PAGE,
+                                        prefill_chunk=CHUNK)
+        r = run_engine_timed(make, wl, wl)
+        probe = make()
+        rids = [probe.add_request(**req) for req in wl]
+        res = probe.run()
+        rows.append(dict(r, tp=tp, devices=tp,
+                         outputs=[res[rid] for rid in rids]))
     for r in rows:
         assert r["outputs"] == rows[0]["outputs"], \
             f"tp={r['tp']} tokens diverged from tp=1"
@@ -223,12 +221,14 @@ def _lane_tp(summary):
 def run():
     cfg = get_smoke_config("qwen2-72b")
     rt = ModelRuntime(cfg, key=jax.random.PRNGKey(0))
-    summary = {"backend": jax.default_backend(), "arch": cfg.name,
+    dev = jax.devices()[0]
+    summary = {"backend": dev.platform, "device_kind": dev.device_kind,
+               "device_count": jax.device_count(), "arch": cfg.name,
                "page_size": PAGE, "prefill_chunk": CHUNK}
     _lane_bytes(rt, cfg, summary)
     _lane_slots(rt, cfg, summary)
     _lane_hol(rt, summary)
-    _lane_tp(summary)
+    _lane_tp(cfg, summary)
     if TINY:
         write_summary("kv", summary)
 

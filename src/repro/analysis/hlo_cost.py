@@ -33,13 +33,6 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 
-def normalize_cost_analysis(ca) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` returns a dict on recent jax and a
-    one-element list of dicts on older versions; normalize to a dict."""
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca or {}
-
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w\.\-]+)\s*=\s*")
 _OPCODE_RE = re.compile(r"([\w\-]+)\(")

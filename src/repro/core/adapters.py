@@ -192,8 +192,11 @@ def gsoft_apply_T(spec: AdapterSpec, params: Params, x: Array) -> Array:
     """x -> x Q = (Q^T x^T)^T: rotate the activations instead of the weight."""
     b = spec.resolved_block(spec.d_in, spec.block_size)
     lay = gsoft_layout(spec.d_in, b)
+    # rotate in the activation dtype (bf16 activations, bf16 blocks), as
+    # the weight-side path rotates in the weight dtype
     L = cayley(skew(params["L"]), neumann_order=spec.neumann_order)
     R = cayley(skew(params["R"]), neumann_order=spec.neumann_order)
+    L, R = L.astype(x.dtype), R.astype(x.dtype)
     if spec.use_pallas:
         return kernel_ops.gs_transform_T(L, R, x, use_pallas=True)
     return gs.gs_apply_T(lay, L, R, x)

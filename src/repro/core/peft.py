@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from . import methods as methods_lib
-from .adapters import AdapterSpec, init_adapter, materialize
+from .adapters import (AdapterSpec, apply_activation_side, init_adapter,
+                       materialize)
 
 Array = jnp.ndarray
 Tree = Any
@@ -156,13 +157,66 @@ def init_peft(cfg: PEFTConfig, params: Tree, key: jax.Array,
 # materialization / merge
 # ---------------------------------------------------------------------------
 
+@jax.tree_util.register_pytree_with_keys_class
+@dataclasses.dataclass(frozen=True)
+class AdaptedWeight:
+    """A frozen weight and its adapter, applied where a layer uses it.
+
+    ``materialize_tree(..., lazy=True)`` puts these in place of the weights
+    the layers consume through ``models.layers.qlinear``, which calls
+    ``apply``: input-rotation methods rotate the activations, x (Q W) =
+    (x Q) W, so no adapted weight is ever built; other methods build W_eff
+    right before the matmul. Inside the model's layer scan the children
+    are the current layer's slices, so at most one layer's adapted weights
+    exist at a time. Mirrors the weight's ``shape``/``ndim``/``dtype`` like
+    ``QuantTensor`` does.
+    """
+    w: Array
+    adapter: Dict[str, Array]
+    cfg: PEFTConfig
+
+    def tree_flatten_with_keys(self):
+        return (((jax.tree_util.GetAttrKey("w"), self.w),
+                 (jax.tree_util.GetAttrKey("adapter"), self.adapter)),
+                self.cfg)
+
+    @classmethod
+    def tree_unflatten(cls, cfg, children):
+        return cls(w=children[0], adapter=children[1], cfg=cfg)
+
+    @property
+    def shape(self):
+        return self.w.shape
+
+    @property
+    def ndim(self) -> int:
+        return self.w.ndim
+
+    @property
+    def dtype(self):
+        return self.w.dtype
+
+    def apply(self, x: Array) -> Tuple[Array, Array]:
+        """(x', W') with x' @ W' == x @ W_eff."""
+        spec = spec_for(self.cfg, tuple(self.w.shape))
+        if (methods_lib.get(spec.method).apply_activation_side is None
+                or spec.use_scale):
+            return x, materialize(spec, self.adapter, self.w)
+        return apply_activation_side(spec, self.adapter, x).astype(
+            x.dtype), self.w
+
+
 def materialize_tree(cfg: PEFTConfig, params: Tree,
                      adapters: Dict[str, Dict[str, Array]],
-                     merged: bool = False) -> Tree:
-    """Effective parameter tree with adapters applied (weight-side).
+                     merged: bool = False, lazy: bool = False) -> Tree:
+    """Effective parameter tree with adapters applied (weight-side unless
+    ``lazy``). Weight-side cost is O(2 b d n) per adapted weight — a ~b/T
+    fraction of the corresponding GEMM for T tokens (DESIGN §3).
 
-    Runs inside jit each step; cost is O(2 b d n) per adapted weight —
-    a ~b/T fraction of the corresponding GEMM for T tokens (DESIGN §3).
+    ``lazy=True`` (the train step) defers every weight the layers consume
+    through ``qlinear`` to the point of use as an ``AdaptedWeight``;
+    weights consumed elsewhere (expert stacks, SSM projections) are
+    materialized here as before.
 
     ``merged=True`` documents the offline single-merge call sites (serving:
     adapters folded into the weights once, zero per-token overhead — paper
@@ -172,14 +226,24 @@ def materialize_tree(cfg: PEFTConfig, params: Tree,
     del merged  # intent marker only — same math either way
     if not adapters:
         return params
+    from repro.quant.weights import DEFAULT_QUANT_TARGETS as QLINEAR_WEIGHTS
 
     def visit(path, leaf):
         p = path_str(path)
-        if p in adapters:
-            return materialize(spec_for(cfg, leaf.shape), adapters[p], leaf)
-        return leaf
+        if p not in adapters:
+            return leaf
+        if lazy and matches_patterns(QLINEAR_WEIGHTS, p):
+            return AdaptedWeight(leaf, adapters[p], cfg)
+        return _materialize_leaf(spec_for(cfg, leaf.shape), adapters[p],
+                                 leaf)
 
     return jax.tree_util.tree_map_with_path(visit, params)
+
+
+# One compiled program per adapted weight: called outside jit (the offline
+# merge), XLA then bounds the rotation's temporaries to that weight instead
+# of holding every eager intermediate. Inside jit it simply inlines.
+_materialize_leaf = jax.jit(materialize, static_argnums=0)
 
 
 # ---------------------------------------------------------------------------

@@ -256,13 +256,16 @@ class ModelRuntime:
         if self.mesh is not None and isinstance(bank, peft_lib.AdapterBank):
             # eager bank: commit factor stacks onto the serve mesh
             # (replicated unless the method's bank_shard_axes hook opts a
-            # factor axis into the 'model' split). The store-paged bank is
-            # left alone — its stacks are rewritten in place on every
-            # page-in, so it keeps default placement.
+            # factor axis into the 'model' split)
             from repro.sharding import specs as shard_specs
             rules = shard_specs.ShardingRules(self.cfg, self.mesh)
             bank.tree = shard_specs.place(self.mesh, bank.tree,
                                           rules.bank_spec_tree(bank.tree))
+        elif self.mesh is not None:
+            # store-paged bank: stacks replicated over the serve mesh; its
+            # page-ins write in place and keep that placement
+            bank.place(jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec()))
         rt = ModelRuntime(self.cfg, self.params, mesh=self.mesh, bank=bank)
         rt.quant_cfg = self.quant_cfg   # quantize-then-bank commutes
         self._adopt_jit(rt)
@@ -454,8 +457,9 @@ class ModelRuntime:
         return self._jit["paged_decode"]
 
     def chunk_prefill_fn(self):
-        """jitted (params, req, state, slot, start) -> (first, state);
-        state donated. One trace per chunk width (req token shape)."""
+        """jitted (params, req, state, slot, start) -> (first,
+        last_logits, state); state donated. One trace per chunk width (req
+        token shape)."""
         if self._jit.get("chunk_prefill") is None:
             from repro.train.steps import build_chunk_prefill_step
             self._jit["chunk_prefill"] = jax.jit(

@@ -23,19 +23,22 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.launch.mesh import make_mesh
 
 
-def serve_mesh(tp: int, dp: int = 1) -> Mesh:
+def serve_mesh(tp: int, dp: int = 1, devices=None) -> Mesh:
     """The serving mesh for ``--tp N``: (dp, tp) over ("data", "model").
     tp=1 still yields a real (degenerate) mesh so the placement path is
-    identical whether or not the model is actually split."""
+    identical whether or not the model is actually split. ``devices``
+    names the chips to use (default: the first dp*tp visible ones) — a
+    1x1 mesh over one device is how a replica is committed to its chip."""
     if tp < 1 or dp < 1:
         raise ValueError(f"tp={tp} and dp={dp} must be >= 1")
     n = tp * dp
-    if n > len(jax.devices()):
+    devices = list(devices or jax.devices()[:n])
+    if len(devices) != n:
+        visible = jax.devices()
         raise ValueError(
-            f"serve mesh needs {n} devices, only {len(jax.devices())} "
-            "visible — set XLA_FLAGS=--xla_force_host_platform_device_count "
-            "for CPU testing")
-    return make_mesh(dp, tp)
+            f"serve mesh needs {n} devices; got {len(devices)} (the "
+            f"{visible[0].platform} backend shows {len(visible)})")
+    return make_mesh(dp, tp, devices=devices)
 
 
 def head_shard_map(fn: Callable, mesh: Mesh,
@@ -52,8 +55,6 @@ def head_shard_map(fn: Callable, mesh: Mesh,
     tp-tagged ``kernels.dispatch`` keys resolve tunings for — the full
     array's launch geometry can be illegal for the shard.
     """
-    from jax.experimental.shard_map import shard_map
-
     def spec(ax):
         if ax is None:
             return P()
@@ -62,5 +63,5 @@ def head_shard_map(fn: Callable, mesh: Mesh,
         return P(*s)
 
     in_specs = tuple(spec(ax) for ax in head_axes)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=spec(out_head_axis), check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=spec(out_head_axis), check_vma=False)

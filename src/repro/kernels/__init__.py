@@ -1,9 +1,10 @@
 """Pallas TPU kernels for the framework's compute hot-spots.
 
   bdmm     — block-diagonal (grouped) matmul: the GS "group" primitive
-  gs_fused — fused GSOFT rotation P^T L P R x (one HBM round-trip), its
-             transpose rotation Q^T x, and a fused backward producing
-             (dx, dL, dR) in a single pass
+  gs_fused — GSOFT rotation P^T L P R x, its transpose rotation Q^T x,
+             and the backward (dx, dL, dR), built from bdmm launches with
+             the P shuffle between them
+  q_matmul — int8-weight matmul with the dequant in the epilogue
   ssd      — Mamba2 state-space-dual chunked scan (mamba2/zamba2 archs)
 
 Each kernel has a pure-jnp oracle in ref.py; ops.py is the jit-friendly
@@ -13,8 +14,9 @@ dispatch used by the model code.
 ------------------------
 ``False`` (default) runs the reference path — identical math via XLA, used
 on backends where Mosaic cannot lower (launch/dryrun.py pins it off).
-``True`` runs ``pl.pallas_call``; on a non-TPU backend the call transparently
-drops to interpret mode so tests/examples exercise the kernel bodies on CPU.
+``True`` runs ``pl.pallas_call``: compiled on TPU, interpreted on the CPU
+backend so tests exercise the kernel bodies there; any other backend raises
+(``dispatch.interpret_mode``).
 Both settings are fully differentiable: the Pallas path installs the
 ``jax.custom_vjp`` rules from dispatch.py, whose backward passes are Pallas
 kernels too (transposed-blocks bdmm + token-contraction for bdmm; the

@@ -18,10 +18,10 @@ This module is the single place that decides *how* a GS kernel call runs:
         bdmm:  dx = bdmm(blocks^T, dy);  dblocks = token-contraction kernel.
         gs:    dx = Q^T dy (the transpose rotation is itself a GS transform —
                the paper's structure makes the VJP closed under the class);
-               dL/dR from the fused backward kernel (activations stay in
-               VMEM, fp32 accumulation over token tiles).
+               dL/dR from bdmm token-contraction kernels (fp32
+               accumulation over token tiles).
         gs_T:  dx = Q dy (the forward kernel); dL/dR via the identity
-               <g, Q^T x> = <x, Q g>, i.e. the same fused backward kernel
+               <g, Q^T x> = <x, Q g>, i.e. the same factor-gradient kernels
                with (input, cotangent) swapped.
 
 Autotuning is *eager* (it times real kernel launches), so call it from
@@ -73,8 +73,17 @@ def _backend() -> str:
     return jax.default_backend()
 
 
-def _interpret() -> bool:
-    return _backend() != "tpu"
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode: on the CPU backend
+    (tests), never on the TPU. Any other backend has no path for these
+    kernels, so it raises rather than silently interpreting."""
+    backend = _backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run compiled on TPU or interpreted "
+                       f"on CPU; backend {backend!r} is neither")
 
 
 # Serve-time tensor parallelism (ISSUE 8): under ``shard_map``/GSPMD each
@@ -338,7 +347,7 @@ def autotune_bdmm(r: int, bo: int, bi: int, t: int, dtype=jnp.float32, *,
     k1, k2 = jax.random.split(jax.random.PRNGKey(0))
     blocks = jax.random.normal(k1, (r, bo, bi), dtype)
     x = jax.random.normal(k2, (t, r * bi), dtype)
-    interp = _interpret()
+    interp = interpret_mode()
     best, best_us = None, float("inf")
     for tt in token_tiles:
         for gt in group_tiles:
@@ -363,7 +372,7 @@ def autotune_gs(r: int, b: int, t: int, dtype=jnp.float32, *,
     L = jax.random.normal(ks[0], (r, b, b), dtype)
     R = jax.random.normal(ks[1], (r, b, b), dtype)
     x = jax.random.normal(ks[2], (t, r * b), dtype)
-    interp = _interpret()
+    interp = interpret_mode()
     best, best_us = None, float("inf")
     for tt in token_tiles:
         fn = jax.jit(functools.partial(
@@ -393,7 +402,7 @@ def autotune_qmm(k: int, n: int, t: int, dtype=jnp.bfloat16, *,
     x = jax.random.normal(k1, (t, k), dtype)
     q = jax.random.randint(k2, (k, n), -127, 128, jnp.int8)
     scale = jnp.full((1, n), 1e-2, jnp.float32)
-    interp = _interpret()
+    interp = interpret_mode()
     best, best_us = None, float("inf")
     for tt in token_tiles:
         for nt in n_tiles:
@@ -443,7 +452,7 @@ bdmm_diff.defvjp(_bdmm_fwd, _bdmm_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def gs_diff(tuning: Tuning, interpret: bool, L: Array, R: Array,
             x: Array) -> Array:
-    """Differentiable fused GSOFT rotation  y = P^T L P R x."""
+    """Differentiable GSOFT rotation  y = P^T L P R x."""
     return gs_fused_pallas(L, R, x, token_tile=tuning.token_tile,
                            interpret=interpret)
 

@@ -1,227 +1,102 @@
-"""Pallas TPU kernel: fused GSOFT transform  y = P^T L P R x.
+"""GSOFT rotations on the Pallas path:  y = P^T L P R x  and its transpose.
 
-The unfused path costs 4 HBM round-trips of the activation (R-matmul, shuffle,
-L-matmul, unshuffle — XLA usually fuses some but keeps the transpose copies).
-This kernel keeps a (token_tile, d) slab resident in VMEM and performs
-group -> shuffle -> group -> unshuffle entirely on-chip: exactly one HBM read
-of x and one write of y.  The P_(r,d) shuffle is a reshape/swap on VMEM data
-(a Mosaic relayout, no HBM traffic) — the TPU-native realization of the
-paper's "shuffle is free" property.
+Each group stage is one launch of the block-diagonal ``bdmm`` kernel
+(kernels/bdmm.py): tokens on sublanes, whole 128-lane groups of weight
+columns per grid step. The P_(r,d) shuffle between the stages is a
+reshape + transpose of the (T, d) activation that XLA performs between the
+launches. The shuffle is kept out of the kernel bodies on purpose: inside
+a kernel it splits the lane dimension, (T, r*b) -> (T, r, b) with b < 128,
+and Mosaic refuses that shape cast at real widths (d 8192, b 32).
 
-Constraint: token_tile * d * (2 dtypes) + 2*d*b*4 bytes must fit VMEM
-(~16 MB); ops.py falls back to two bdmm calls for oversized d.
+Intermediates round to ``x.dtype`` between stages, exactly as the oracle
+``ref.gs_fused_ref`` does. The backward pass is built from the same two
+kernels: ``bdmm`` for the activation gradient and ``bdmm_dblocks`` for the
+factor gradients (fp32, accumulated over token tiles in VMEM).
 """
 from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
-import jax.experimental.pallas as pl
+
+from .bdmm import bdmm_dblocks_pallas, bdmm_pallas
 
 Array = jnp.ndarray
 
 
-def _gs_fused_kernel(x_ref, l_ref, r_ref, o_ref, *, r: int, b: int):
-    t = x_ref.shape[0]
-    d = r * b
-    x = x_ref[...]                                   # (t, d)
-    f32 = jnp.float32
-
-    # R x  — grouped right-multiplication (tokens on lanes)
-    xg = x.reshape(t, r, b)
-    R = r_ref[...]                                   # (r, b, b)
-    y = jax.lax.dot_general(xg, R, (((2,), (2,)), ((1,), (0,))),
-                            preferred_element_type=f32)   # (r, t, b)
-
-    # P (k = r): flat feature index g*b+i  ->  i*r+g. y is (r, t, b); laying it
-    # out as (t, i, g) IS the shuffled order, so one transpose + regroup does P.
-    y = y.transpose(1, 2, 0)                         # (t, b, r): [t, i, g]
-    L = l_ref[...]                                   # (r, b, b) blocks of L
-    y = y.reshape(t, r, b)                           # regroup for L's blocks
-    z = jax.lax.dot_general(y, L, (((2,), (2,)), ((1,), (0,))),
-                            preferred_element_type=f32)   # (r, t, b)
-    # P^T: inverse shuffle (k = b): (r_groups, b) -> interleave back
-    z = z.transpose(1, 0, 2)                         # (t, r, b)
-    z = z.reshape(t, d).reshape(t, b, r).transpose(0, 2, 1).reshape(t, d)
-    o_ref[...] = z.astype(o_ref.dtype)
+def _shuffle(y: Array, r: int, b: int) -> Array:
+    """P (k = r): flat feature g*b + i moves to i*r + g."""
+    t = y.shape[0]
+    return y.reshape(t, r, b).swapaxes(1, 2).reshape(t, r * b)
 
 
-def _gs_fused_T_kernel(x_ref, l_ref, r_ref, o_ref, *, r: int, b: int):
-    t = x_ref.shape[0]
-    d = r * b
-    x = x_ref[...]                                   # (t, d)
-    f32 = jnp.float32
-
-    # P x (k = r): shuffle, then regroup for L's blocks
-    s = x.reshape(t, r, b).transpose(0, 2, 1).reshape(t, r, b)
-    L = l_ref[...]                                   # (r, b, b)
-    # L^T .  — q[g,t,j] = sum_i L[g,i,j] s[t,g,i]
-    q = jax.lax.dot_general(s, L, (((2,), (1,)), ((1,), (0,))),
-                            preferred_element_type=f32)   # (r, t, b)
-    # P^T (k = b): inverse shuffle, regroup for R's blocks
-    m = q.transpose(1, 0, 2).reshape(t, d)
-    m = m.reshape(t, b, r).transpose(0, 2, 1).reshape(t, r, b)
-    R = r_ref[...]
-    # R^T .
-    z = jax.lax.dot_general(m, R, (((2,), (1,)), ((1,), (0,))),
-                            preferred_element_type=f32)   # (r, t, b)
-    o_ref[...] = z.transpose(1, 0, 2).reshape(t, d).astype(o_ref.dtype)
+def _unshuffle(y: Array, r: int, b: int) -> Array:
+    """P^T (k = b): the inverse of ``_shuffle``."""
+    t = y.shape[0]
+    return y.reshape(t, b, r).swapaxes(1, 2).reshape(t, r * b)
 
 
-def _gs_fused_bwd_kernel(dy_ref, x_ref, l_ref, r_ref, *out_refs,
-                         r: int, b: int, with_dx: bool):
-    """Fused backward: one read of (x, dy), all intermediates in VMEM.
-
-    Recomputes the cheap forward intermediates (2*d*b flops/token) instead
-    of saving them — residuals are just (x, L, R), so the bwd HBM traffic is
-    one slab read of x and dy plus the block factors.  with_dx=False skips
-    the dx rotation and its slab write entirely (the gs_T VJP needs only
-    the factor grads from this kernel).
-    """
-    if with_dx:
-        dx_ref, dl_ref, dr_ref = out_refs
-    else:
-        dl_ref, dr_ref = out_refs
-    ti = pl.program_id(0)
-    t = dy_ref.shape[0]
-    d = r * b
-    f32 = jnp.float32
-    dy = dy_ref[...]
-    x = x_ref[...]
-    L = l_ref[...]
-    R = r_ref[...]
-
-    xg = x.reshape(t, r, b)
-    # forward intermediates:  u = R x  (grouped),  v = P u  (shuffled groups)
-    u = jax.lax.dot_general(xg, R, (((2,), (2,)), ((1,), (0,))),
-                            preferred_element_type=f32)   # (r, t, b)
-    v = u.transpose(1, 2, 0).reshape(t, r, b)
-    # dw = P dy  (y = P^T w  =>  w-cotangent is the shuffled dy)
-    dw = dy.reshape(t, r, b).transpose(0, 2, 1).reshape(t, r, b)
-    # dL[g, i, j] = sum_t dw[t, g, i] v[t, g, j]
-    dL = jax.lax.dot_general(dw, v, (((0,), (0,)), ((1,), (1,))),
-                             preferred_element_type=f32)  # (r, b, b)
-    # dv = L^T dw
-    dv = jax.lax.dot_general(dw, L, (((2,), (1,)), ((1,), (0,))),
-                             preferred_element_type=f32)  # (r, t, b)
-    # du = P^T dv  (back to original grouping)
-    du = dv.transpose(1, 0, 2).reshape(t, d)
-    du = du.reshape(t, b, r).transpose(0, 2, 1).reshape(t, r, b)
-    # dR[g, i, j] = sum_t du[t, g, i] x[t, g, j]
-    dR = jax.lax.dot_general(du, xg.astype(f32),
-                             (((0,), (0,)), ((1,), (1,))),
-                             preferred_element_type=f32)  # (r, b, b)
-    if with_dx:
-        # dx = R^T du
-        dx = jax.lax.dot_general(du, R, (((2,), (1,)), ((1,), (0,))),
-                                 preferred_element_type=f32)  # (r, t, b)
-        dx_ref[...] = dx.transpose(1, 0, 2).reshape(t, d).astype(dx_ref.dtype)
-
-    @pl.when(ti == 0)
-    def _init():
-        dl_ref[...] = dL
-        dr_ref[...] = dR
-
-    @pl.when(ti != 0)
-    def _acc():
-        dl_ref[...] += dL
-        dr_ref[...] += dR
-
-
-def _call_gs_kernel(kernel, L: Array, R: Array, x: Array,
-                    token_tile: int, interpret: bool) -> Array:
-    r, b, _ = L.shape
-    t, d = x.shape
-    assert d == r * b
-    tt = min(token_tile, t)
-    pad = (-t) % tt
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-    tp = x.shape[0]
-    out = pl.pallas_call(
-        functools.partial(kernel, r=r, b=b),
-        out_shape=jax.ShapeDtypeStruct((tp, d), x.dtype),
-        grid=(tp // tt,),
-        in_specs=[
-            pl.BlockSpec((tt, d), lambda ti: (ti, 0)),
-            pl.BlockSpec((r, b, b), lambda ti: (0, 0, 0)),
-            pl.BlockSpec((r, b, b), lambda ti: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tt, d), lambda ti: (ti, 0)),
-        interpret=interpret,
-    )(x, L, R)
-    return out[:t] if pad else out
+def _bd(token_tile: int, interpret: bool):
+    return functools.partial(bdmm_pallas, token_tile=token_tile,
+                             interpret=interpret)
 
 
 def gs_fused_pallas(L: Array, R: Array, x: Array, *, token_tile: int = 128,
                     interpret: bool = False) -> Array:
     """L, R: (r, b, b); x: (T, d=r*b) -> (T, d). y = P^T L P R x."""
-    return _call_gs_kernel(_gs_fused_kernel, L, R, x, token_tile, interpret)
+    r, b, _ = L.shape
+    assert x.shape[-1] == r * b, (L.shape, x.shape)
+    bd = _bd(token_tile, interpret)
+    return _unshuffle(bd(L, _shuffle(bd(R, x), r, b)), r, b)
 
 
 def gs_fused_T_pallas(L: Array, R: Array, x: Array, *, token_tile: int = 128,
                       interpret: bool = False) -> Array:
-    """Transpose rotation  y = R^T P^T L^T P x  (= Q^T x), same VMEM budget.
+    """Transpose rotation  y = R^T P^T L^T P x  (= Q^T x).
 
     This is both the VJP of gs_fused_pallas w.r.t. x and the activation-side
     adapter application (x Q = (Q^T x^T)^T).
     """
-    return _call_gs_kernel(_gs_fused_T_kernel, L, R, x, token_tile, interpret)
-
-
-def _call_gs_bwd(L: Array, R: Array, x: Array, dy: Array, *,
-                 token_tile: int, interpret: bool, with_dx: bool):
     r, b, _ = L.shape
-    t, d = x.shape
-    assert d == r * b and dy.shape == x.shape
-    tt = min(token_tile, t)
-    pad = (-t) % tt
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-        dy = jnp.pad(dy, ((0, pad), (0, 0)))
-    tp = x.shape[0]
-    grad_shape = jax.ShapeDtypeStruct((r, b, b), jnp.float32)
-    grad_spec = pl.BlockSpec((r, b, b), lambda ti: (0, 0, 0))
-    slab_spec = pl.BlockSpec((tt, d), lambda ti: (ti, 0))
-    out_shape = (grad_shape, grad_shape)
-    out_specs = (grad_spec, grad_spec)
-    if with_dx:
-        out_shape = (jax.ShapeDtypeStruct((tp, d), x.dtype),) + out_shape
-        out_specs = (slab_spec,) + out_specs
-    outs = pl.pallas_call(
-        functools.partial(_gs_fused_bwd_kernel, r=r, b=b, with_dx=with_dx),
-        out_shape=out_shape,
-        grid=(tp // tt,),
-        in_specs=[slab_spec, slab_spec, grad_spec, grad_spec],
-        out_specs=out_specs,
-        interpret=interpret,
-    )(dy, x, L, R)
-    if with_dx:
-        dx, dL, dR = outs
-        return (dx[:t] if pad else dx), dL, dR
-    return outs
+    assert x.shape[-1] == r * b, (L.shape, x.shape)
+    bd = _bd(token_tile, interpret)
+    y = _unshuffle(bd(jnp.swapaxes(L, -1, -2), _shuffle(x, r, b)), r, b)
+    return bd(jnp.swapaxes(R, -1, -2), y)
+
+
+def _gs_bwd(L: Array, R: Array, x: Array, dy: Array, *, token_tile: int,
+            interpret: bool, with_dx: bool):
+    r, b, _ = L.shape
+    assert x.shape[-1] == r * b and dy.shape == x.shape
+    bd = _bd(token_tile, interpret)
+    dblocks = functools.partial(bdmm_dblocks_pallas, bo=b, bi=b,
+                                token_tile=token_tile, interpret=interpret)
+    v = _shuffle(bd(R, x), r, b)                    # forward: P R x
+    dw = _shuffle(dy, r, b)                         # y = P^T w  =>  dw = P dy
+    dL = dblocks(dw, v)                             # sum_t dw_g v_g^T
+    du = _unshuffle(bd(jnp.swapaxes(L, -1, -2), dw), r, b)   # P^T L^T dw
+    dR = dblocks(du, x)                             # sum_t du_g x_g^T
+    if not with_dx:
+        return dL, dR
+    return bd(jnp.swapaxes(R, -1, -2), du), dL, dR  # dx = R^T du
 
 
 def gs_fused_bwd_pallas(L: Array, R: Array, x: Array, dy: Array, *,
                         token_tile: int = 128, interpret: bool = False):
-    """Fused backward of  y = P^T L P R x.
+    """Backward of  y = P^T L P R x.
 
-    Returns (dx, dL, dR) with dx in x.dtype and dL, dR accumulated in fp32:
+    Returns (dx, dL, dR) with dx in x.dtype and dL, dR in fp32:
         dx = Q^T dy,   dL[g] = sum_t (P dy)_g (P R x)_g^T,
         dR[g] = sum_t (P^T L^T P dy)_g x_g^T.
-
-    One grid pass over token tiles; dL/dR output blocks are revisited every
-    step and accumulated in place, the activation slab never leaves VMEM.
     """
-    return _call_gs_bwd(L, R, x, dy, token_tile=token_tile,
-                        interpret=interpret, with_dx=True)
+    return _gs_bwd(L, R, x, dy, token_tile=token_tile, interpret=interpret,
+                   with_dx=True)
 
 
 def gs_fused_grads_pallas(L: Array, R: Array, x: Array, dy: Array, *,
                           token_tile: int = 128, interpret: bool = False):
-    """Factor gradients only: (dL, dR) of <dy, P^T L P R x> — no dx slab
-    is computed or written (used by the gs_T VJP, which gets its dx from
-    the forward rotation of dy instead)."""
-    return _call_gs_bwd(L, R, x, dy, token_tile=token_tile,
-                        interpret=interpret, with_dx=False)
+    """Factor gradients only: (dL, dR) of <dy, P^T L P R x>. No dx is
+    computed (used by the gs_T VJP, which gets its dx from the forward
+    rotation of dy instead)."""
+    return _gs_bwd(L, R, x, dy, token_tile=token_tile, interpret=interpret,
+                   with_dx=False)

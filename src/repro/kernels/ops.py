@@ -4,8 +4,9 @@
   * False  — pure-jnp reference path (XLA). Always used by launch/dryrun.py:
              TPU kernels cannot lower on the CPU dry-run backend, and the
              reference path is semantically identical (tests prove it).
-  * True   — pl.pallas_call; on a non-TPU backend this transparently runs in
-             interpret mode so examples/tests exercise the kernel body on CPU.
+  * True   — pl.pallas_call: compiled on TPU, interpreted on the CPU
+             backend so tests exercise the kernel bodies there
+             (``dispatch.interpret_mode``; any other backend raises).
 
 Both paths are differentiable: the reference path via XLA autodiff, the
 Pallas path via the custom-VJP rules in dispatch.py (backward passes are
@@ -25,15 +26,11 @@ import jax
 import jax.numpy as jnp
 
 from . import dispatch, ref
-from .dispatch import Tuning
+from .dispatch import Tuning, interpret_mode
 from .flash_attention import flash_attention, paged_flash_decode
 from .ssd import ssd_pallas
 
 Array = jnp.ndarray
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def bdmm(blocks: Array, x: Array, use_pallas: bool = False,
@@ -45,7 +42,7 @@ def bdmm(blocks: Array, x: Array, use_pallas: bool = False,
         r, bo, bi = blocks.shape
         tun = tuning or dispatch.get_tuning(dispatch.bdmm_key(r, bo, bi,
                                                               x.dtype))
-        y = dispatch.bdmm_diff(tun, _interpret(), blocks, x2)
+        y = dispatch.bdmm_diff(tun, interpret_mode(), blocks, x2)
     else:
         y = ref.bdmm_ref(blocks, x2)
     return y.reshape(lead + (y.shape[-1],))
@@ -64,7 +61,7 @@ def gs_transform(L: Array, R: Array, x: Array, use_pallas: bool = False,
     """y = P^T L P R x (GSOFT rotation) over the last dim of x."""
     lead, x2, tun = _gs_2d(L, x)
     if use_pallas:
-        y = dispatch.gs_diff(tuning or tun, _interpret(), L, R, x2)
+        y = dispatch.gs_diff(tuning or tun, interpret_mode(), L, R, x2)
     else:
         y = ref.gs_fused_ref(L, R, x2)
     return y.reshape(lead + (x.shape[-1],))
@@ -79,7 +76,7 @@ def gs_transform_T(L: Array, R: Array, x: Array, use_pallas: bool = False,
     """
     lead, x2, tun = _gs_2d(L, x)
     if use_pallas:
-        y = dispatch.gs_T_diff(tuning or tun, _interpret(), L, R, x2)
+        y = dispatch.gs_T_diff(tuning or tun, interpret_mode(), L, R, x2)
     else:
         y = ref.gs_fused_T_ref(L, R, x2)
     return y.reshape(lead + (x.shape[-1],))
@@ -96,7 +93,7 @@ def bdmm_banked(blocks: Array, x: Array, use_pallas: bool = False,
         _, r, bo, bi = blocks.shape
         tun = tuning or dispatch.get_tuning(dispatch.bdmm_key(r, bo, bi,
                                                               x.dtype))
-        interp = _interpret()
+        interp = interpret_mode()
         return jax.vmap(
             lambda bb, xx: dispatch.bdmm_diff(tun, interp, bb, xx))(blocks, x)
     return ref.bdmm_banked_ref(blocks, x)
@@ -115,7 +112,7 @@ def gs_banked_transform_T(L: Array, R: Array, x: Array,
     if use_pallas:
         _, r, b, _bb = L.shape
         tun = tuning or dispatch.get_tuning(dispatch.gs_key(r, b, x.dtype))
-        interp = _interpret()
+        interp = interpret_mode()
         return jax.vmap(
             lambda l, rr, xx: dispatch.gs_T_diff(tun, interp, l, rr, xx))(
                 L, R, x)
@@ -162,7 +159,7 @@ def q_matmul(x: Array, q: Array, scale: Array, use_pallas: bool = False,
         y = dispatch.q_matmul_pallas(x2, q, scale,
                                      token_tile=tun.token_tile,
                                      n_tile=tun.group_tile,
-                                     interpret=_interpret())
+                                     interpret=interpret_mode())
     else:
         # fp8 codes (and the no-kernel path) run the reference einsum
         y = ref.q_matmul_ref(x2, q, scale)
@@ -184,7 +181,7 @@ def gs_q_matmul(L: Array, R: Array, x: Array, q: Array, scale: Array,
         y = dispatch.gs_q_matmul_pallas(L, R, x2, q, scale,
                                         token_tile=tun.token_tile,
                                         n_tile=tun.group_tile,
-                                        interpret=_interpret())
+                                        interpret=interpret_mode())
     else:
         y = ref.gs_q_matmul_ref(L, R, x2, q, scale)
     return y.reshape(lead + (y.shape[-1],))
@@ -202,7 +199,7 @@ def gs_q_matmul_banked(L: Array, R: Array, x: Array, q: Array, scale: Array,
         n = q.shape[-1]
         tun = tuning or dispatch.get_tuning(
             dispatch.gs_qmm_key(r, b, n, x.dtype))
-        interp = _interpret()
+        interp = interpret_mode()
         return jax.vmap(
             lambda l, rr, xx: dispatch.gs_q_matmul_pallas(
                 l, rr, xx, q, scale, token_tile=tun.token_tile,
@@ -229,7 +226,7 @@ def paged_attention(q: Array, k_pages: Array, v_pages: Array, table: Array,
         # the persisted tuning cache covers this call site too
         dispatch.get_tuning(dispatch.paged_attn_key(h, kh, d, page, q.dtype))
         return paged_flash_decode(q, k_pages, v_pages, table, kv_len,
-                                  scale=scale, interpret=_interpret())
+                                  scale=scale, interpret=interpret_mode())
     return ref.paged_attn_ref(q, k_pages, v_pages, table, kv_len, scale=scale)
 
 
@@ -245,7 +242,7 @@ def ssd(x: Array, loga: Array, B: Array, C: Array, chunk: int = 64,
         while t % q:
             q //= 2
         return ssd_pallas(x, loga, B, C, chunk=max(q, 1),
-                          interpret=_interpret())
+                          interpret=interpret_mode())
     return ref.ssd_chunked_ref(x, loga, B, C,
                                chunk=dispatch.pick_chunk(x.shape[0], chunk))
 
@@ -266,7 +263,7 @@ def flash_mha(q: Array, k: Array, v: Array, *, causal: bool = True,
     if use_pallas:
         fn = lambda qq, kk, vv: flash_attention(
             qq, kk, vv, causal=causal, blk_q=blk, blk_k=blk,
-            interpret=_interpret())
+            interpret=interpret_mode())
     else:
         fn = lambda qq, kk, vv: ref.flash_ref(qq, kk, vv, causal=causal)
     out = jax.vmap(fn)(qh, kkh, vvh)

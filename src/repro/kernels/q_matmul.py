@@ -4,146 +4,122 @@ The decode step is memory-bandwidth-bound: every token re-reads the whole
 weight tree from HBM. Storing base weights as int8 codes + fp32
 per-output-channel scales halves (vs bf16) the bytes the matmul streams;
 the kernel widens the int8 tile to the activation dtype IN VMEM (a VPU
-cast, no extra HBM traffic), runs the MXU dot, and applies the scales in
-the fp32 epilogue before the output cast — the dequantized float weight
-never exists in HBM.
+cast, no extra HBM traffic), runs the MXU dot into an fp32 accumulator,
+and applies the scales in the epilogue before the output cast — the
+dequantized float weight never exists in HBM.
 
-``gs_q_matmul`` is the adapter-serving fusion: the activation-side GSOFT
-rotation x·Q (transpose rotation, same math as kernels/gs_fused.py) runs
-in the activation dtype on the VMEM slab, then feeds the quantized base
-matmul directly — one HBM read of x, one of the int8 weight, one write of
-y for the whole rotate+project step. Rotations stay bf16 per the
-QOFT/OFTv2 rationale (int8 would break Cayley orthogonality; the factors
-are O(r·b²) anyway — the memory win lives in the O(d²) base weights).
+Grid: (token tiles, out-channel tiles, contraction tiles), contraction
+innermost. Tiles along N and K are multiples of 128 (the lane width) or
+the whole dimension, which is what Mosaic accepts for the (tt, K) and
+(K, N) blocks; tiling K keeps the down projection of a 29568-wide MLP
+inside the scoped VMEM limit.
 
-Grid: (token tiles, out-channel tiles); the contraction dim K stays whole
-per step (weights enter VMEM as (K, n_tile) int8 — 2 bytes/param cheaper
-than bf16, which is the point).
+``gs_q_matmul`` is the adapter-serving composition: the activation-side
+GSOFT rotation x·Q (kernels/gs_fused.py, bf16) followed by this kernel.
+Rotations stay bf16 per the QOFT/OFTv2 rationale (int8 would break Cayley
+orthogonality; the factors are O(r·b²) anyway — the memory win lives in
+the O(d²) base weights).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 import jax.experimental.pallas as pl
+import jax.experimental.pallas.tpu as pltpu
+
+from .gs_fused import gs_fused_T_pallas
 
 Array = jnp.ndarray
 
+LANE = 128
 
-def default_n_tile(n: int, cap: int = 256) -> int:
-    """Largest divisor of n that is <= cap (out-channel tile)."""
-    t = min(cap, n)
+
+def _lane_tile(n: int, cap: int) -> int:
+    """Largest multiple of 128 that divides n and is <= cap; n itself when
+    n is not a multiple of 128 (one block then spans the dimension)."""
+    if n % LANE:
+        return n
+    t = max(min(cap, n) // LANE, 1) * LANE
     while n % t:
-        t -= 1
-    return max(t, 1)
+        t -= LANE
+    return t
 
 
-def _prep(x: Array, scale, n: int, token_tile: int, n_tile: int):
-    """Shared launch prologue: broadcast the scale to (1, n), resolve the
-    out-channel tile to a divisor of n, pad tokens to the token tile.
-    Returns (x_padded, scale, n_tile, token_tile, pad)."""
-    t = x.shape[0]
-    s = jnp.broadcast_to(jnp.asarray(scale, jnp.float32).reshape(1, -1)
-                         if jnp.ndim(scale) else
-                         jnp.full((1, n), scale, jnp.float32), (1, n))
-    if n_tile <= 0:
-        n_tile = default_n_tile(n)
-    while n % n_tile:
-        n_tile -= 1
-    tt = min(token_tile, t)
-    pad = (-t) % tt
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-    return x, s, n_tile, tt, pad
+def default_n_tile(n: int, cap: int = 512) -> int:
+    """Out-channel tile: a multiple of 128 dividing n, or the full n."""
+    return _lane_tile(n, cap)
 
 
-def _q_matmul_kernel(x_ref, q_ref, s_ref, o_ref):
-    x = x_ref[...]                                   # (tt, K) activation dtype
+def default_k_tile(k: int) -> int:
+    """Contraction tile: a multiple of 128 dividing k, at most 2048, or the
+    full k."""
+    return _lane_tile(k, 2048)
+
+
+def _q_matmul_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref):
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    x = x_ref[...]                                   # (tt, kt)
     w = q_ref[...].astype(x.dtype)                   # int8 -> bf16 in VMEM
-    y = jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    o_ref[...] = (y * s_ref[...]).astype(o_ref.dtype)   # epilogue dequant
+    acc_ref[...] += jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _out():                                      # epilogue dequant
+        o_ref[...] = (acc_ref[...] * s_ref[...]).astype(o_ref.dtype)
 
 
 def q_matmul_pallas(x: Array, q: Array, scale: Array, *,
                     token_tile: int = 128, n_tile: int = 0,
                     interpret: bool = False) -> Array:
-    """x: (T, K); q: (K, N) int8; scale: (1, N) or scalar fp32 -> (T, N)."""
+    """x: (T, K); q: (K, N) int8; scale: (1, N) or scalar fp32 -> (T, N).
+
+    ``n_tile`` of 0 picks the default; any other value is rounded down to a
+    legal tile (a multiple of 128 dividing N). K is tiled by
+    ``default_k_tile``."""
     t, k = x.shape
     kq, n = q.shape
     assert k == kq, (x.shape, q.shape)
-    x, s, n_tile, tt, pad = _prep(x, scale, n, token_tile, n_tile)
+    s = jnp.broadcast_to(jnp.asarray(scale, jnp.float32).reshape(1, -1)
+                         if jnp.ndim(scale) else
+                         jnp.full((1, n), scale, jnp.float32), (1, n))
+    nt = default_n_tile(n, n_tile or 512)
+    kt = default_k_tile(k)
+    tt = min(token_tile, t)
+    pad = (-t) % tt
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
     tp = x.shape[0]
     out = pl.pallas_call(
         _q_matmul_kernel,
         out_shape=jax.ShapeDtypeStruct((tp, n), x.dtype),
-        grid=(tp // tt, n // n_tile),
+        grid=(tp // tt, n // nt, k // kt),
         in_specs=[
-            pl.BlockSpec((tt, k), lambda ti, ni: (ti, 0)),
-            pl.BlockSpec((k, n_tile), lambda ti, ni: (0, ni)),
-            pl.BlockSpec((1, n_tile), lambda ti, ni: (0, ni)),
+            pl.BlockSpec((tt, kt), lambda ti, ni, ki: (ti, ki)),
+            pl.BlockSpec((kt, nt), lambda ti, ni, ki: (ki, ni)),
+            pl.BlockSpec((1, nt), lambda ti, ni, ki: (0, ni)),
         ],
-        out_specs=pl.BlockSpec((tt, n_tile), lambda ti, ni: (ti, ni)),
+        out_specs=pl.BlockSpec((tt, nt), lambda ti, ni, ki: (ti, ni)),
+        scratch_shapes=[pltpu.VMEM((tt, nt), jnp.float32)],
         interpret=interpret,
     )(x, q, s)
     return out[:t] if pad else out
 
 
-def _gs_q_matmul_kernel(x_ref, l_ref, r_ref, q_ref, s_ref, o_ref, *,
-                        r: int, b: int):
-    t = x_ref.shape[0]
-    d = r * b
-    x = x_ref[...]                                   # (tt, d)
-    f32 = jnp.float32
-
-    # transpose GS rotation x Q = (R^T P^T L^T P x^T)^T, all on the VMEM
-    # slab (same math as gs_fused._gs_fused_T_kernel), fp32 accumulation,
-    # result dropped back to the activation dtype before the base matmul
-    sh = x.reshape(t, r, b).transpose(0, 2, 1).reshape(t, r, b)      # P
-    L = l_ref[...]
-    u = jax.lax.dot_general(sh, L, (((2,), (1,)), ((1,), (0,))),
-                            preferred_element_type=f32)              # L^T .
-    m = u.transpose(1, 0, 2).reshape(t, d)                           # P^T
-    m = m.reshape(t, b, r).transpose(0, 2, 1).reshape(t, r, b)
-    R = r_ref[...]
-    z = jax.lax.dot_general(m, R, (((2,), (1,)), ((1,), (0,))),
-                            preferred_element_type=f32)              # R^T .
-    xr = z.transpose(1, 0, 2).reshape(t, d).astype(x.dtype)
-
-    w = q_ref[...].astype(x.dtype)                   # (d, nt) int8 -> bf16
-    y = jax.lax.dot_general(xr, w, (((1,), (0,)), ((), ())),
-                            preferred_element_type=f32)
-    o_ref[...] = (y * s_ref[...]).astype(o_ref.dtype)
-
-
 def gs_q_matmul_pallas(L: Array, R: Array, x: Array, q: Array, scale: Array,
                        *, token_tile: int = 128, n_tile: int = 0,
                        interpret: bool = False) -> Array:
-    """Fused (x Q_gs) @ W_q. L, R: (r, b, b); x: (T, d=r*b); q: (d, N).
+    """(x Q_gs) @ W_q. L, R: (r, b, b); x: (T, d=r*b); q: (d, N).
 
-    The rotation recomputes per out-channel tile — O(t·d·b) VPU/MXU work
-    against the O(t·d·n_tile) base matmul, a cheap trade for keeping the
-    rotated slab out of HBM entirely.
-    """
+    The rotation runs in the activation dtype and its result feeds the
+    quantized matmul kernel, matching ``ref.gs_q_matmul_ref``."""
     r, b, _ = L.shape
-    t, d = x.shape
-    dq, n = q.shape
-    assert d == r * b == dq, (L.shape, x.shape, q.shape)
-    x, s, n_tile, tt, pad = _prep(x, scale, n, token_tile, n_tile)
-    tp = x.shape[0]
-    out = pl.pallas_call(
-        functools.partial(_gs_q_matmul_kernel, r=r, b=b),
-        out_shape=jax.ShapeDtypeStruct((tp, n), x.dtype),
-        grid=(tp // tt, n // n_tile),
-        in_specs=[
-            pl.BlockSpec((tt, d), lambda ti, ni: (ti, 0)),
-            pl.BlockSpec((r, b, b), lambda ti, ni: (0, 0, 0)),
-            pl.BlockSpec((r, b, b), lambda ti, ni: (0, 0, 0)),
-            pl.BlockSpec((d, n_tile), lambda ti, ni: (0, ni)),
-            pl.BlockSpec((1, n_tile), lambda ti, ni: (0, ni)),
-        ],
-        out_specs=pl.BlockSpec((tt, n_tile), lambda ti, ni: (ti, ni)),
-        interpret=interpret,
-    )(x, L, R, q, s)
-    return out[:t] if pad else out
+    assert x.shape[-1] == r * b == q.shape[0], (L.shape, x.shape, q.shape)
+    xr = gs_fused_T_pallas(L, R, x, token_tile=token_tile,
+                           interpret=interpret)
+    return q_matmul_pallas(xr, q, scale, token_tile=token_tile,
+                           n_tile=n_tile, interpret=interpret)

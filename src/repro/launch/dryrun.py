@@ -137,8 +137,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, peft: str = "gsoft",
 
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
-        from repro.analysis.hlo_cost import module_cost, normalize_cost_analysis
-        cost = normalize_cost_analysis(compiled.cost_analysis())
+        from repro.analysis.hlo_cost import module_cost
+        cost = compiled.cost_analysis()
         mem = _mem_dict(compiled.memory_analysis())
         hlo = compiled.as_text()
         # trip-count-aware accounting (XLA's cost_analysis counts while

@@ -6,14 +6,10 @@ from __future__ import annotations
 import jax
 
 
-def make_axes_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; meshes default to Auto axes
-    # there, so omitting the argument is equivalent on older versions.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_axes_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,9 +20,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_axes_mesh(shape, axes)
 
 
-def make_mesh(data: int, model: int, pods: int = 1):
+def make_mesh(data: int, model: int, pods: int = 1, devices=None):
     """Elastic variant: any (pods x data x model) that fits the device count
-    (used by tests and by elastic-restart re-sharding)."""
+    (used by tests and by elastic-restart re-sharding). ``devices`` picks
+    the chips (default: the first ones visible)."""
     if pods > 1:
-        return make_axes_mesh((pods, data, model), ("pod", "data", "model"))
-    return make_axes_mesh((data, model), ("data", "model"))
+        return make_axes_mesh((pods, data, model), ("pod", "data", "model"),
+                              devices)
+    return make_axes_mesh((data, model), ("data", "model"), devices)

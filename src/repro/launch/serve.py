@@ -39,6 +39,7 @@ adapter bank.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 
@@ -49,6 +50,7 @@ from repro.config import get_config, get_smoke_config, parse_overrides
 from repro.core import peft as peft_lib
 from repro.core.runtime import ModelRuntime
 from repro.distrib import EngineCluster, format_cluster_report, serve_mesh
+from repro.launch import compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.obs import SLOMonitor, TraceRecorder
@@ -72,6 +74,46 @@ def make_demo_adapters(names, params, peft_cfg, seed=1, scale=0.1):
         out[name] = jax.tree.map(
             lambda a, k=key: a + scale * jax.random.normal(
                 jax.random.fold_in(k, 7), a.shape), ad)
+    return out
+
+
+def _replica(rt: ModelRuntime, mesh, hbm_budget) -> ModelRuntime:
+    """A copy of ``rt`` committed to ``mesh``: same params (re-placed),
+    quantization and adapters, with its own bank."""
+    from repro.store import PagedAdapterBank
+    r = ModelRuntime(rt.cfg, rt.params, mesh=mesh)
+    r.quant_cfg = rt.quant_cfg
+    if isinstance(rt.bank, PagedAdapterBank):
+        return r.attach(rt.bank.store, hbm_budget=hbm_budget)
+    if rt.bank is not None:         # attach re-places a shallow bank copy
+        return r.attach(dataclasses.replace(rt.bank))
+    return r
+
+
+def replica_runtimes(rt: ModelRuntime, n: int, *, hbm_budget=None):
+    """Runtimes for N engine replicas of ``rt``; replica 0 is ``rt``.
+
+    Without a serve mesh, replica i is committed to device i mod the
+    visible count through a 1x1 serve mesh, so its params, KV pages and
+    bank stacks (eager or store-paged) live on its own chip. Replicas that land on one
+    device (or share a serve mesh) share one runtime: engines keep their
+    own KV state, and weights and compiled closures exist once. A store-
+    paged bank forces a fresh runtime per replica: paging state
+    (residency, pins, LRU order) must be per replica for the cluster's
+    adapter-affinity routing to mean anything."""
+    from repro.store import PagedAdapterBank
+    paged = isinstance(rt.bank, PagedAdapterBank)
+    devices = jax.devices()
+    out, on_device = [rt], {devices[0]: rt}
+    for i in range(1, n):
+        if rt.mesh is not None:
+            out.append(_replica(rt, rt.mesh, hbm_budget) if paged else rt)
+            continue
+        dev = devices[i % len(devices)]
+        if paged or dev not in on_device:
+            on_device[dev] = _replica(rt, serve_mesh(1, devices=[dev]),
+                                      hbm_budget)
+        out.append(on_device[dev])
     return out
 
 
@@ -227,6 +269,7 @@ def main():
                          "report (throttled by --report-interval)")
     ap.add_argument("--set", nargs="*", default=[])
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     cfg = cfg.with_overrides(**parse_overrides(args.set))
@@ -331,24 +374,6 @@ def main():
               f"{before / 1e6:.2f} MB -> {after / 1e6:.2f} MB "
               f"({before / max(after, 1):.2f}x smaller)")
 
-    def replica_runtimes(n: int):
-        """Runtimes for N engine replicas. Stateless runtimes (bankless,
-        eager bank, merged, quantized) are SHARED — engines keep their own
-        KV state, and jitted closures/weights exist once. Only a store-
-        paged bank forces a fresh runtime per replica: paging state
-        (residency, pins, LRU order) must be per-replica for the
-        cluster's adapter-affinity routing to mean anything."""
-        from repro.store import PagedAdapterBank
-        if n == 1 or not isinstance(rt.bank, PagedAdapterBank):
-            return [rt] * n
-        out = [rt]
-        for _ in range(n - 1):
-            r = base_rt.attach(rt.bank.store, hbm_budget=budget)
-            if args.quantize != "none":
-                r = r.quantized(args.quantize)
-            out.append(r)
-        return out
-
     # ---- observability: one tracer + SLO monitor across every lane ---------
     want_trace = (args.trace or args.trace_out is not None
                   or args.report_interval > 0)
@@ -364,10 +389,11 @@ def main():
     elif stateless:
         engines = [ImageServeEngine(r, max_batch=args.max_batch,
                                     tracer=tracer)
-                   for r in replica_runtimes(args.replicas)]
+                   for r in replica_runtimes(rt, args.replicas,
+                                             hbm_budget=budget)]
         eng = EngineCluster(engines, slo=slo)
     else:
-        rts = replica_runtimes(args.replicas)
+        rts = replica_runtimes(rt, args.replicas, hbm_budget=budget)
         if args.engine == "paged":
             engines = [PagedServeEngine(r, max_batch=args.max_batch,
                                         max_len=max_len,

@@ -4,9 +4,10 @@
         --arch qwen2-72b --smoke --peft gsoft --steps 200 \
         --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
 
-On a real pod: run under launch/scripts/run_with_restart.sh with
---mesh data,model sized to the slice (jax.distributed.initialize is called
-when JAX_COORDINATOR is set).  In this container it runs single-process.
+Multi-device: pass --mesh data,model sized to the visible devices. When
+JAX_COORDINATOR is set, jax.distributed.initialize() is called first
+(multi-host pods). The persistent compile cache goes where
+repro.launch.compile_cache says.
 """
 from __future__ import annotations
 
@@ -20,6 +21,7 @@ from repro.config import get_config, get_smoke_config, parse_overrides
 from repro.core import methods as methods_lib
 from repro.core import peft as peft_lib
 from repro.data import DataConfig
+from repro.launch import compile_cache
 from repro.launch.mesh import make_mesh
 from repro.optim import schedules
 from repro.train.loop import LoopConfig, train
@@ -48,6 +50,7 @@ def main():
     ap.add_argument("--no-resume", action="store_true")
     ap.add_argument("--set", nargs="*", default=[])
     args = ap.parse_args()
+    compile_cache.enable()
 
     if os.environ.get("JAX_COORDINATOR"):
         jax.distributed.initialize()  # multi-host pods

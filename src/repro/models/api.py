@@ -11,6 +11,7 @@ out so they cannot return.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -30,7 +31,10 @@ def family_ops(cfg: ModelConfig) -> registry.FamilyOps:
     return registry.get(cfg.family)
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key: jax.Array):
+    """Jitted, so each weight's fp32 draw fuses into its cast: the device
+    holds the params, never an fp32 copy of a bf16 weight."""
     return family_ops(cfg).init_params(cfg, key)
 
 

@@ -15,6 +15,7 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.peft import AdaptedWeight
 from repro.kernels import ops as kernel_ops
 from repro.quant.core import QuantTensor
 
@@ -46,7 +47,12 @@ def qlinear(x: Array, w, rot: Optional[Callable[[str, Array], Array]] = None,
     ``cast=True`` pre-casts a PLAIN weight to the activation dtype (the
     lm_head/patch_proj call sites, whose weights may be wider than the
     activations); quantized matmuls already return ``x.dtype``.
+
+    An ``AdaptedWeight`` (the train step's lazily adapted weight) is
+    applied here, inside the layer that uses it.
     """
+    if isinstance(w, AdaptedWeight):
+        x, w = w.apply(x)
     if isinstance(w, QuantTensor):
         factors = None
         if rot is not None:

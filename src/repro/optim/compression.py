@@ -57,8 +57,6 @@ def compressed_psum_mean(tree: Tree, err: Tree, mesh, axes: Tuple[str, ...]):
     replicated over ``axes`` holding the *local* contribution (the standard
     per-shard gradient before psum).
     """
-    from jax.experimental.shard_map import shard_map
-
     n = 1
     for a in axes:
         n *= mesh.shape[a]
@@ -80,7 +78,7 @@ def compressed_psum_mean(tree: Tree, err: Tree, mesh, axes: Tuple[str, ...]):
         return red, new_e
 
     spec = jax.tree.map(lambda _: P(), tree)
-    fn = shard_map(inner, mesh=mesh,
-                   in_specs=(spec, spec), out_specs=(spec, spec),
-                   check_rep=False)
+    fn = jax.shard_map(inner, mesh=mesh,
+                       in_specs=(spec, spec), out_specs=(spec, spec),
+                       check_vma=False)
     return fn(tree, err)

@@ -20,6 +20,7 @@ as static aux data — so quantized parameter trees flow through ``jit``,
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -29,13 +30,9 @@ Array = jnp.ndarray
 
 INT8_MAX = 127.0
 # fp8 e4m3 finite max (jax calls it float8_e4m3fn); the fp8 path is a
-# STUB: it exists so the scale/metadata plumbing is exercised, but only
-# runs where jax exposes the dtype, and only via the reference matmul.
+# STUB: it exists so the scale/metadata plumbing is exercised, and runs
+# only via the reference matmul.
 FP8_MAX = 448.0
-
-
-def fp8_supported() -> bool:
-    return hasattr(jnp, "float8_e4m3fn")
 
 
 def _absmax_scale(x32: Array, axis: Optional[int], qmax: float,
@@ -50,6 +47,10 @@ def _absmax_scale(x32: Array, axis: Optional[int], qmax: float,
     return jnp.maximum(amax, 1e-12) / qmax
 
 
+# jitted so that quantizing a weight outside jit (ModelRuntime.quantized)
+# fuses the fp32 widening into the reduction and the rounding: a bf16
+# weight never gets a materialized fp32 copy on the device.
+@functools.partial(jax.jit, static_argnames=("axis", "batch_dims"))
 def quantize_int8(x: Array, axis: Optional[int] = None,
                   batch_dims: int = 0) -> Tuple[Array, Array]:
     """Symmetric int8 quantization -> (q int8, scale fp32).
@@ -70,14 +71,11 @@ def dequantize_int8(q: Array, scale: Array) -> Array:
     return q.astype(jnp.float32) * scale
 
 
+@functools.partial(jax.jit, static_argnames=("axis", "batch_dims"))
 def quantize_fp8(x: Array, axis: Optional[int] = None,
                  batch_dims: int = 0) -> Tuple[Array, Array]:
-    """fp8 (e4m3) cast with absmax scaling — stub path, gated on dtype
-    support in the installed jax/backend."""
-    if not fp8_supported():
-        raise NotImplementedError(
-            "fp8 quantization needs jnp.float8_e4m3fn, which this jax "
-            "build does not expose — use mode='int8'")
+    """fp8 (e4m3) cast with absmax scaling — stub path (reference
+    matmul only)."""
     x32 = x.astype(jnp.float32)
     scale = _absmax_scale(x32, axis, FP8_MAX, batch_dims)
     q = (x32 / scale).astype(jnp.float8_e4m3fn)

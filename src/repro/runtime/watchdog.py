@@ -1,8 +1,8 @@
 """Fault-tolerance runtime: heartbeats, straggler detection, crash recovery.
 
-On real pods the launcher (launch/scripts/run_with_restart.sh) restarts a
-failed worker from the latest committed checkpoint; this module provides the
-host-side signals it consumes:
+An external supervisor restarts a failed worker from the latest committed
+checkpoint (the repo ships none); this module provides the host-side
+signals such a supervisor consumes:
 
   * Heartbeat      — train loop touches a file every step; an external
                      watchdog (watch_heartbeat) kills/reforms if it goes

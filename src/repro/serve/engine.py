@@ -748,7 +748,7 @@ class PagedServeEngine(ServeEngine):
         if self.tracer is not None:                # span per prompt chunk
             self.tracer.prefill_start(self._ttag, req.rid)
         with self._annot("prefill_chunk"):
-            first, self._state = self._chunk_prefill(
+            first, _, self._state = self._chunk_prefill(
                 self.rt.params, feed, self._state,
                 jnp.asarray(slot, jnp.int32), jnp.asarray(start, jnp.int32))
         if self.tracer is not None:

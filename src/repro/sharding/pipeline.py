@@ -16,7 +16,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jnp.ndarray
@@ -65,9 +64,9 @@ def gpipe_forward(stage_fn: Callable, stage_params: Any, x_mb: Array,
                             axis)
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(per_device, mesh=mesh,
-                   in_specs=(pspec, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(per_device, mesh=mesh,
+                       in_specs=(pspec, P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x_mb)
 
 

@@ -319,6 +319,15 @@ class PagedAdapterBank:
             [self._stacks[p][method][k] for p, pg in pages.items()
              for k in pg])
 
+    def place(self, sharding) -> None:
+        """Commit every stack to ``sharding`` (a replica's own device or
+        its serve mesh). Page-ins update the stacks in place, so they keep
+        the placement."""
+        for entry in self._stacks.values():
+            for factors in entry.values():
+                for k, v in factors.items():
+                    factors[k] = jax.device_put(v, sharding)
+
     # -- accounting -----------------------------------------------------------
     def resident_bytes(self) -> int:
         """HBM held by the compact stacks (identity slots included)."""

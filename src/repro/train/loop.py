@@ -2,8 +2,8 @@
 
 Wiring (per DESIGN §4): deterministic data by (seed, step) — resume replays
 exactly; checkpoints carry adapters + optimizer + data cursor; heartbeat +
-step-time straggler detection feed the restart wrapper
-(launch/scripts/run_with_restart.sh).
+step-time straggler detection are the signals an external restart
+supervisor consumes (runtime/watchdog.py).
 """
 from __future__ import annotations
 
@@ -99,6 +99,7 @@ def train(cfg: ModelConfig, tcfg: TrainStepConfig, dcfg: DataConfig,
             loss = float(metrics["loss"])
             history.append({"step": step, "loss": loss,
                             "accuracy": float(metrics["accuracy"]),
+                            "grad_norm": float(metrics["grad_norm"]),
                             "step_time_s": t["step_time_s"],
                             "straggler": t["straggler"]})
             log_fn(f"step {step:5d} loss {loss:.4f} "

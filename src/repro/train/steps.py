@@ -4,8 +4,10 @@ train_step (PEFT mode — the paper's setting):
     inputs : frozen base params (bf16, no grads), adapter params (fp32,
              trainable), optimizer state (adapters only), batch
     body   : scan over microbatches -> mean adapter grads -> AdamW update
-    GSOFT adapters are materialized weight-side inside the step
-    (core.peft.materialize_tree) — zero extra collectives under TP.
+    Adapters apply inside the layer that uses each weight
+    (core.peft.materialize_tree(lazy=True) -> AdaptedWeight): GSOFT and the
+    other input-rotation methods rotate activations, x (Q W) = (x Q) W, so
+    no adapted weight is built — zero extra collectives under TP.
 
 serve_step: decode_step over a sharded KV cache / SSM state (cache donated);
 ``pos`` may be per-slot (continuous batching) and an optional
@@ -75,7 +77,8 @@ def build_train_step(cfg: ModelConfig, tcfg: TrainStepConfig,
 
     def loss_fn(trainable, frozen, mb):
         if is_peft:
-            params = peft_lib.materialize_tree(peft_cfg, frozen, trainable)
+            params = peft_lib.materialize_tree(peft_cfg, frozen, trainable,
+                                               lazy=True)
         else:
             params = trainable
         loss, metrics = api.loss_fn(cfg, params, mb, shard)
@@ -121,7 +124,8 @@ def build_eval_step(cfg: ModelConfig, tcfg: TrainStepConfig,
     peft_cfg = _resolve_peft(cfg, tcfg)
 
     def eval_step(frozen, trainable, batch):
-        params = (peft_lib.materialize_tree(peft_cfg, frozen, trainable)
+        params = (peft_lib.materialize_tree(peft_cfg, frozen, trainable,
+                                            lazy=True)
                   if peft_cfg.is_peft else trainable)
         _, metrics = api.loss_fn(cfg, params, batch, shard)
         return metrics
@@ -177,10 +181,11 @@ def build_chunk_prefill_step(cfg: ModelConfig, mesh: Optional[Mesh] = None,
                              batch_divisible: bool = True):
     """Chunked-prefill admission unit: ONE fixed-width prompt chunk for ONE
     slot, written through that slot's page table. Returns
-    step(params, req, state, slot, start) -> (first_token scalar, state);
-    the chunk width is static (one trace per width), slot/start are traced
-    scalars, and the returned first_token is only meaningful on the final
-    chunk (req.last_idx marks the prompt's last valid token there)."""
+    step(params, req, state, slot, start) -> (first_token scalar,
+    last_logits (V,), state); the chunk width is static (one trace per
+    width), slot/start are traced scalars, and first_token / last_logits
+    (the logits at req.last_idx) are only meaningful on the final chunk,
+    where req.last_idx marks the prompt's last valid token."""
     shard = (ShardingRules(cfg, mesh).make_sharder(batch_divisible)
              if mesh is not None else no_shard)
     fam = api.family_ops(cfg)
@@ -191,7 +196,7 @@ def build_chunk_prefill_step(cfg: ModelConfig, mesh: Optional[Mesh] = None,
         logits, new_state = fam.paged_chunk_prefill(cfg, params, req, state,
                                                     slot, start, shard)
         first = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[0]
-        return first, new_state
+        return first, logits[0, -1], new_state
 
     return chunk_step
 

@@ -5,8 +5,9 @@ mesh must serve TOKEN-IDENTICAL to the single-device runtime through the
 real engines — contiguous ServeEngine with a mixed-method eager bank
 (tp=2 divides the smoke model's heads, tp=4 exceeds its kv heads so the
 KV spec falls back to replicated), the int8-quantized runtime (QuantTensor
-trees placed leaf-wise), and the paged engine (KV pages head-sharded,
-page table replicated). Prints one JSON line per check."""
+trees placed leaf-wise), the paged engine (KV pages head-sharded, page
+table replicated), and one-device replicas (launch.serve.replica_runtimes)
+with eager and store-paged banks. Prints one JSON line per check."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
@@ -20,7 +21,7 @@ from repro.config import get_smoke_config
 from repro.core import peft as peft_lib
 from repro.core.runtime import ModelRuntime
 from repro.distrib import serve_mesh
-from repro.launch.serve import make_demo_adapters
+from repro.launch.serve import make_demo_adapters, replica_runtimes
 from repro.serve.engine import PagedServeEngine, ServeEngine
 
 OUT = []
@@ -89,6 +90,24 @@ def main():
     got_pg = run_engine(ModelRuntime(cfg, key=key, mesh=serve_mesh(2)),
                         wl_pg, paged=True)
     check("serve/tp2/paged_tokens_equal", got_pg == ref_pg)
+
+    # one replica per device: params, KV pages and bank stacks (eager, and
+    # store-paged so that page-ins run) stay on the replica's own device
+    wl_rep = workload(6, seed=2, adapters=[None, "g0", "b0"])
+    ref_rep = run_engine(rt_solo.attach(adapters, bank_peft), wl_rep,
+                         paged=True)
+    for label, budget in (("eager", None), ("paged", 2)):
+        reps = replica_runtimes(
+            rt_solo.attach(adapters, bank_peft, hbm_budget=budget), 4,
+            hbm_budget=budget)
+        got = [run_engine(r, wl_rep, paged=True) for r in reps]
+        homes = [sorted({d.id for leaf in jax.tree.leaves(
+                     (r.params, r.bank.tree)) for d in leaf.sharding.device_set})
+                 for r in reps]
+        check(f"serve/replicas/{label}/one_device_each",
+              homes == [[i] for i in range(4)], homes=homes)
+        check(f"serve/replicas/{label}/tokens_equal",
+              all(g == ref_rep for g in got))
 
     for rec in OUT:
         print("CHECK " + json.dumps(rec))

@@ -174,6 +174,7 @@ def _drive_runner(name, min_checks):
 @pytest.mark.distributed
 def test_serve_tp_stack():
     """Sharded serving == single-device serving, token for token (bf16
-    eager mixed-method bank, int8 quantized, paged KV), on 8 fake CPU
+    eager mixed-method bank, int8 quantized, paged KV), and one replica per
+    device (eager and store-paged banks) on its own device, on 8 fake CPU
     devices."""
-    _drive_runner("serve_distributed_runner.py", min_checks=6)
+    _drive_runner("serve_distributed_runner.py", min_checks=10)

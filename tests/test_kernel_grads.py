@@ -22,6 +22,7 @@ from repro.core import peft as peft_lib
 from repro.kernels import dispatch, ops, ref
 from repro.kernels.gs_fused import (gs_fused_T_pallas, gs_fused_bwd_pallas,
                                     gs_fused_grads_pallas)
+from repro.models.layers import qlinear
 
 KEY = jax.random.PRNGKey(0)
 
@@ -232,11 +233,12 @@ def test_gsoft_adapter_loss_grad_matches_reference(method):
 
 
 def test_peft_tree_grad_matches_reference():
-    """materialize_tree (the train-step path) with use_pallas=True: adapter
-    grads through a whole params tree match the reference path."""
+    """materialize_tree(lazy=True) applied through qlinear (the train-step
+    path) with use_pallas=True: adapter grads through a whole params tree
+    match the reference path."""
     params = {
-        "layer0": {"wq": jax.random.normal(KEY, (32, 32)),
-                   "wo": jax.random.normal(jax.random.PRNGKey(1), (32, 32))},
+        "attn": {"wq": jax.random.normal(KEY, (32, 32)),
+                 "wo": jax.random.normal(jax.random.PRNGKey(1), (32, 32))},
     }
     cfg = peft_lib.PEFTConfig(method="gsoft", block_size=8)
     cfg_pallas = dataclasses.replace(cfg, use_pallas=True)
@@ -246,9 +248,10 @@ def test_peft_tree_grad_matches_reference():
     x = jax.random.normal(jax.random.PRNGKey(3), (8, 32))
 
     def loss(adp, c):
-        eff = peft_lib.materialize_tree(c, params, adp)
-        h = jnp.tanh(x @ eff["layer0"]["wq"])
-        return jnp.mean((h @ eff["layer0"]["wo"]) ** 2)
+        eff = peft_lib.materialize_tree(c, params, adp, lazy=True)
+        assert isinstance(eff["attn"]["wq"], peft_lib.AdaptedWeight)
+        h = jnp.tanh(qlinear(x, eff["attn"]["wq"]))
+        return jnp.mean(qlinear(h, eff["attn"]["wo"]) ** 2)
 
     g0 = jax.grad(loss)(adapters, cfg)
     g1 = jax.grad(loss)(adapters, cfg_pallas)

@@ -89,14 +89,10 @@ def test_error_feedback_still_converges_after_refactor(rng):
 
 def test_fp8_stub_gated_on_dtype_support(rng):
     w = jnp.asarray(rng.normal(size=(16, 8)), jnp.float32)
-    if quant.fp8_supported():
-        q, s = quant.quantize_fp8(w, axis=-1)
-        assert q.dtype == jnp.float8_e4m3fn
-        err = np.abs(np.asarray(quant.dequantize_fp8(q, s)) - np.asarray(w))
-        assert err.max() < 0.1 * np.abs(np.asarray(w)).max()
-    else:
-        with pytest.raises(NotImplementedError, match="fp8"):
-            quant.quantize_fp8(w, axis=-1)
+    q, s = quant.quantize_fp8(w, axis=-1)
+    assert q.dtype == jnp.float8_e4m3fn
+    err = np.abs(np.asarray(quant.dequantize_fp8(q, s)) - np.asarray(w))
+    assert err.max() < 0.1 * np.abs(np.asarray(w)).max()
 
 
 # ---------------------------------------------------------------------------
@@ -110,6 +106,7 @@ QMM_SHAPES = [
     (33, 48, 96),        # ragged T (padding path)
     (1, 64, 64),         # decode-shaped: single token
     (250, 24, 40),       # N not a multiple of the default tile
+    (16, 4096, 1024),    # 2 K tiles x 2 N tiles: the fp32 accumulator path
 ]
 
 
