@@ -121,7 +121,6 @@ def run():
          f"evictions={st['evictions']};hit_rate={st['hit_rate']:.2f}")
 
     cluster = _lane_cluster(rt)
-    tracing = _lane_tracing(rt, workload, max_batch, max_len)
 
     if TINY:
         summary = {"backend": jax.default_backend(), "arch": cfg.name,
@@ -131,60 +130,7 @@ def run():
             for key, val in r.items():
                 summary[f"{name}_{key}"] = val
         summary.update(cluster)
-        summary.update(tracing)
         write_summary("serve", summary)
-
-
-def _lane_tracing(rt, workload, max_batch, max_len):
-    """Tracing-overhead bound (ISSUE 10): a ``TraceRecorder`` + ``SLOMonitor``
-    attached to the continuous engine must cost at most 5% throughput —
-    the hooks are host-side list appends off the jitted dispatch path.
-    Off/on runs are INTERLEAVED in pairs and each side keeps its best,
-    so machine drift across the sweep hits both sides alike and one
-    scheduler hiccup doesn't fail the bound (a couple of extra pairs run
-    before declaring a miss); every finished request must carry a
-    complete span set (submit/prefill/first-token/finish) with TTFT/TPOT
-    percentiles in the SLO report."""
-    from repro.obs import SLOMonitor, TraceRecorder
-
-    make_plain = lambda: ServeEngine(rt, max_batch=max_batch,  # noqa: E731
-                                     max_len=max_len, eos_id=-1)
-    tracers = []
-
-    def make_traced():
-        tr = TraceRecorder(slo=SLOMonitor(window=512))
-        tracers.append(tr)
-        return ServeEngine(rt, max_batch=max_batch, max_len=max_len,
-                           eos_id=-1, tracer=tr)
-
-    off = on = None
-    for pair in range(5):
-        r_off = run_engine_timed(make_plain, workload, workload)
-        r_on = run_engine_timed(make_traced, workload, workload)
-        if off is None or r_off["tok_s"] > off["tok_s"]:
-            off = r_off
-        if on is None or r_on["tok_s"] > on["tok_s"]:
-            on = r_on
-        if pair >= 2 and on["tok_s"] >= 0.97 * off["tok_s"]:
-            break                        # bound met with margin; stop early
-    for tr in tracers:                  # warmup + timed pass both traced
-        done = tr.finished
-        assert len(done) == 2 * len(workload), \
-            f"expected {2 * len(workload)} finished traces, got {len(done)}"
-        incomplete = [t.rid for t in done if not t.complete]
-        assert not incomplete, f"incomplete spans for rids {incomplete}"
-    rep = tracers[-1].slo.report()
-    assert rep["ttft_ms"]["p95"] > 0 and rep["tpot_ms"]["p50"] > 0, \
-        f"SLO report missing latency percentiles: {rep}"
-    ratio = on["tok_s"] / max(off["tok_s"], 1e-9)
-    emit("serve/tracing_overhead", 0.0,
-         f"on_vs_off=x{ratio:.3f};ttft_p95_ms={rep['ttft_ms']['p95']:.1f};"
-         f"tpot_p50_ms={rep['tpot_ms']['p50']:.2f}")
-    assert ratio >= 0.95, \
-        f"tracing overhead: x{ratio:.3f} of untraced throughput (< x0.95)"
-    return {"tracing_overhead_ratio": ratio,
-            "tracing_ttft_p95_ms": rep["ttft_ms"]["p95"],
-            "tracing_tpot_p50_ms": rep["tpot_ms"]["p50"]}
 
 
 def _poisson_arrivals(n: int, rate: float, seed: int) -> np.ndarray:

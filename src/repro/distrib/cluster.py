@@ -30,6 +30,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from repro.obs.metrics import REGISTRY
 from repro.obs.slo import SLOMonitor
+from repro.obs.trace import merge_span_stats
 from repro.serve.engine import Request
 from repro.serve.kv import merge_pool_stats
 
@@ -116,10 +117,12 @@ class EngineCluster:
         return home, "affinity_hits"
 
     def add_request(self, prompt: List[int], max_new_tokens: int = 16,
-                    adapter: Optional[str] = None) -> int:
+                    adapter: Optional[str] = None,
+                    t_arrival: Optional[float] = None) -> int:
         i, kind = self._route(adapter)
         local = self.engines[i].add_request(prompt, max_new_tokens,
-                                            adapter=adapter)
+                                            adapter=adapter,
+                                            t_arrival=t_arrival)
         self._routing["routed"].inc()
         self._routing[kind].inc()
         crid = self._next_crid
@@ -247,6 +250,8 @@ class EngineCluster:
                "max_resident": sum(p["max_resident"] for p in per),
                "capacity": sum(p["capacity"] for p in per),
                "page_in_ms_p95": max(p["page_in_ms_p95"] for p in per),
+               "page_in_ms_sum": sum(p["page_in_ms_sum"] for p in per),
+               "page_in_count": sum(p["page_in_count"] for p in per),
                "compaction_ratio": sum(p["compaction_ratio"]
                                        for p in per) / n}
         seen = out["hits"] + out["misses"]
@@ -257,6 +262,12 @@ class EngineCluster:
         per = [eng.kv_stats() for eng in self.engines
                if hasattr(eng, "kv_stats")]
         return merge_pool_stats(per) if per else None
+
+    def span_stats(self) -> Dict[str, Dict[str, float]]:
+        """``{span: {"count", "ms"}}`` summed over the replicas' tick spans
+        (``repro.obs.trace.SPANS``), their banks' included."""
+        return merge_span_stats(eng.span_stats() for eng in self.engines
+                                if hasattr(eng, "span_stats"))
 
     def affinity_hit_rate(self) -> float:
         """Fraction of REPEAT-adapter arrivals routed to their warm home.

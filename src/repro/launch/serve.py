@@ -53,7 +53,7 @@ from repro.distrib import EngineCluster, format_cluster_report, serve_mesh
 from repro.launch import compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import registry
-from repro.obs import SLOMonitor, TraceRecorder
+from repro.obs import SLOMonitor, TraceRecorder, compile_counts
 from repro.serve.engine import (PagedServeEngine, ServeEngine,
                                 StaticServeEngine, latency_percentiles)
 from repro.serve.image import ImageServeEngine
@@ -124,14 +124,15 @@ def drive_streaming(eng, requests, arrivals, tick_hook=None):
     ``tick_hook`` (optional) runs after every scheduler tick — the
     launcher's periodic SLO report / --log-json emitter. An SLO-breached
     cluster (``eng.accepting`` False) HOLDS arrivals until the monitor
-    clears — admission backpressure, not drops."""
+    clears — admission backpressure, not drops. Each request carries its
+    arrival time, so TTFT and latency count any hold or late admission."""
     t0 = time.perf_counter()
     i = 0
     while i < len(requests) or not eng.idle:
         now = time.perf_counter() - t0
         while (i < len(requests) and arrivals[i] <= now
                and getattr(eng, "accepting", True)):
-            eng.add_request(**requests[i])
+            eng.add_request(**requests[i], t_arrival=t0 + arrivals[i])
             i += 1
         if eng.idle:                     # nothing in flight: wait for traffic
             time.sleep(min(0.005, max(arrivals[i] - now, 0.0)))
@@ -459,6 +460,9 @@ def main():
     dt = time.perf_counter() - t0
 
     describe(eng, results, args.engine, dt)
+    compiles = compile_counts()
+    print("compiles by span: " + " ".join(
+        f"{k}={v}" for k, v in sorted(compiles.items())))
     if isinstance(eng, EngineCluster):
         # the ONE residency/routing report — replica rows carry the bank
         # and KV-pool residency that used to be printed ad hoc here
@@ -474,7 +478,7 @@ def main():
             "decode_steps": eng.stats["decode_steps"],
             "prefills": eng.stats["prefills"],
             "admission_stalls": eng.stats["admission_stalls"],
-            "wall_s": round(dt, 6),
+            "wall_s": round(dt, 6), "compiles": compiles,
             "slo": slo.report() if slo is not None else None}))
     if tracer is not None and args.trace_out:
         if args.trace_out.endswith(".jsonl"):

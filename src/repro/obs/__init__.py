@@ -7,7 +7,9 @@ are thin views over it.
 * :mod:`repro.obs.metrics` — typed instruments (Counter / Gauge /
   bounded Histogram) in a :class:`MetricsRegistry`.
 * :mod:`repro.obs.trace` — per-request lifecycle spans with TTFT/TPOT
-  and stall attribution; JSONL + Chrome ``trace_event`` export.
+  and stall attribution; JSONL + Chrome ``trace_event`` export; the
+  always-on serving-tick ``span`` (histogram + profiler annotation) and
+  the per-span XLA compile counter.
 * :mod:`repro.obs.slo` — sliding-window percentile monitor with
   threshold callbacks for admission backpressure.
 
@@ -24,7 +26,8 @@ from .metrics import (
     MetricsScope,
 )
 from .slo import SLO_PERCENTILES, SLOMonitor
-from .trace import STALL_REASONS, RequestTrace, TraceRecorder
+from .trace import (SPANS, STALL_REASONS, RequestTrace, TraceRecorder,
+                    compile_counts, span)
 
 __all__ = [
     "DEFAULT_HIST_CAP",
@@ -36,7 +39,10 @@ __all__ = [
     "MetricsScope",
     "SLO_PERCENTILES",
     "SLOMonitor",
+    "SPANS",
     "STALL_REASONS",
     "RequestTrace",
     "TraceRecorder",
+    "compile_counts",
+    "span",
 ]

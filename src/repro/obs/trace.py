@@ -13,15 +13,19 @@ benches report:
     TTFT  time-to-first-token   = t_first  - t_submit
     TPOT  per-token decode gap  = diffs of the token timestamps
 
+``t_submit`` is the request's arrival time where the caller knows it
+(``add_request(..., t_arrival=...)``), so a wait before admission shows
+in TTFT; otherwise it is the moment of ``add_request``.
+
 and STALL ATTRIBUTION — each tick an engine cannot admit the queue head
 it records why (``kv`` pool exhausted, ``adapter`` bank fully pinned, or
 plain ``queue`` head-of-line waiting on a slot), so a latency regression
 names the resource that caused it.
 
 Engines call the recorder only when one is attached (``tracer=None`` is
-the default and costs nothing); every hook is a couple of float appends,
-which is what keeps tracing-on throughput within 5% of off — a bound
-``benchmarks/serve_bench.py`` asserts.
+the default and costs nothing); every hook is a couple of float appends.
+What tracing costs on the chip is measured by the benchmark and kept in
+``PERF.md``.
 
 Keys are ``(engine_tag, rid)``: each engine registers itself once
 (:meth:`TraceRecorder.register_engine`) so a cluster of replicas records
@@ -34,6 +38,13 @@ streamable) or as the Chrome ``trace_event`` format readable by
 ``chrome://tracing`` / Perfetto. An opt-in ``jax.profiler`` hook
 (``annotate``) wraps the jitted prefill/decode dispatches in named
 ``TraceAnnotation`` blocks so device profiles line up with host spans.
+
+The serving tick itself is timed by :class:`span`, always on: each block
+in :data:`SPANS` observes its milliseconds into a registry histogram and
+is a profiler annotation on the device trace's clock, so a profile can
+give each idle gap of the chip to the host work open at the time. XLA
+compiles are counted under the innermost open span (``compiles.<span>``,
+``compiles.none`` outside any), so an operator sees which step recompiled.
 """
 from __future__ import annotations
 
@@ -41,13 +52,94 @@ import contextlib
 import dataclasses
 import json
 import time
-from typing import Any, Dict, List, Optional, TextIO, Tuple
+from typing import Any, Dict, Iterable, List, Optional, TextIO, Tuple
 
-from .metrics import REGISTRY, MetricsRegistry
+import jax.monitoring
+import jax.profiler
+
+from .metrics import REGISTRY, Histogram, MetricsRegistry
 from .slo import SLOMonitor
 
 #: stall attribution reasons engines may record
 STALL_REASONS = ("kv", "adapter", "queue")
+
+#: the serving tick's spans. ``launch`` and ``commit`` are the two halves
+#: of an engine tick; ``admit``, ``prefill_chunk`` and ``decode`` (the
+#: jitted dispatches) sit in ``launch``; the adapter bank's ``page_in``
+#: sits in ``admit``; ``sync`` is every point where the host waits on
+#: device values (a first token, a decode step's tokens, a page-in's
+#: writes). The contiguous engine's whole-prompt dispatch is ``prefill``.
+SPANS = ("launch", "commit", "admit", "prefill_chunk", "decode", "sync",
+         "page_in")
+
+#: the XLA compile event counted under the innermost open span
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# names of the spans open now, innermost last. Module state on purpose:
+# JAX's compile listener is process-wide, and one host thread drives the
+# engines, so the open spans of that thread name each compile.
+_open_spans: List[str] = []
+
+
+class span:
+    """Time a block into ``hist`` (milliseconds, so its exact streaming
+    count and sum read over any window as a delta) and mark it as the
+    profiler annotation ``name``. ``annotate`` is an attached tracer's
+    hook (the engines'); without one the annotation is a
+    ``jax.profiler.TraceAnnotation``, which records only while a profiler
+    session is on."""
+
+    __slots__ = ("name", "hist", "cm", "t0")
+
+    def __init__(self, name: str, hist: Histogram, annotate=None):
+        self.name = name
+        self.hist = hist
+        self.cm = (annotate or jax.profiler.TraceAnnotation)(name)
+
+    def __enter__(self) -> "span":
+        _open_spans.append(self.name)
+        self.cm.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self.t0
+        self.cm.__exit__(*exc)
+        _open_spans.pop()
+        self.hist.observe(dt * 1e3)
+
+
+def span_stats(hists: Dict[str, Histogram]) -> Dict[str, Dict[str, float]]:
+    """``{span: {"count", "ms"}}`` of a map of span name -> histogram."""
+    return {n: {"count": h.count, "ms": h.sum} for n, h in hists.items()}
+
+
+def merge_span_stats(per: Iterable[Dict[str, Dict[str, float]]]
+                     ) -> Dict[str, Dict[str, float]]:
+    """Sum ``span_stats`` dicts by span name (replicas, engine + bank)."""
+    out: Dict[str, Dict[str, float]] = {}
+    for stats in per:
+        for name, s in stats.items():
+            t = out.setdefault(name, {"count": 0, "ms": 0.0})
+            t["count"] += s["count"]
+            t["ms"] += s["ms"]
+    return out
+
+
+def _count_compile(event: str, secs: float, **_) -> None:
+    if event == COMPILE_EVENT:
+        where = _open_spans[-1] if _open_spans else "none"
+        REGISTRY.counter(f"compiles.{where}").inc()
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
+
+
+def compile_counts(registry: MetricsRegistry = REGISTRY) -> Dict[str, int]:
+    """``{span: XLA compiles}`` so far in this process (``none`` = outside
+    any span)."""
+    return {name.split(".", 1)[1]: registry.get(name).value
+            for name in registry.names() if name.startswith("compiles.")}
 
 
 @dataclasses.dataclass
@@ -164,8 +256,8 @@ class TraceRecorder:
     def submit(self, tag: str, rid: int, adapter: Optional[str] = None,
                prompt_len: int = 0,
                t_submit: Optional[float] = None) -> None:
-        """New request. ``t_submit`` carries the ORIGINAL timestamp when a
-        rebalanced request re-enters on another engine."""
+        """New request. ``t_submit`` is the request's arrival time, kept
+        when a rebalanced request re-enters on another engine."""
         self._pending[(tag, rid)] = RequestTrace(
             engine=tag, rid=rid, adapter=adapter, prompt_len=prompt_len,
             t_submit=self.clock() if t_submit is None else t_submit)
@@ -238,7 +330,6 @@ class TraceRecorder:
         otherwise a no-op."""
         if not self.jax_annotations:
             return contextlib.nullcontext()
-        import jax.profiler
         return jax.profiler.TraceAnnotation(name)
 
     # -- export ---------------------------------------------------------------

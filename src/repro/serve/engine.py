@@ -51,7 +51,8 @@ from repro.config import ModelConfig
 from repro.core.peft import PrefillRequest
 from repro.core.runtime import ModelRuntime
 from repro.models import registry
-from repro.obs.metrics import REGISTRY
+from repro.obs.metrics import REGISTRY, Histogram
+from repro.obs.trace import merge_span_stats, span, span_stats
 from .kv import KVPagePool, SlotPages, pages_for_budget
 
 
@@ -62,7 +63,8 @@ class Request:
     max_new_tokens: int = 16
     adapter: Optional[str] = None        # bank adapter name (None = base)
     output: Optional[List[int]] = None
-    # timing (perf_counter seconds; filled by the engines)
+    # timing (perf_counter seconds; filled by the engines). t_submit is the
+    # arrival time where the caller gives one, else the add_request time
     t_submit: float = 0.0
     t_first: float = 0.0
     t_done: float = 0.0
@@ -80,7 +82,9 @@ class EngineMetrics:
     test, bench and driver already uses — same keys as the pre-obs dict,
     one source of truth in ``repro.obs.REGISTRY``. ``admission_log``
     stays a live bounded list: it is a diagnostics ring of
-    ``(rid, decode_step)`` tuples, not a scalar instrument.
+    ``(rid, decode_step)`` tuples, not a scalar instrument. ``spans``
+    holds one ``<span>_ms`` histogram per tick span the engine has run
+    (``repro.obs.trace.SPANS``).
     """
 
     COUNTER_KEYS = ("requests", "tokens_generated", "decode_steps",
@@ -88,9 +92,11 @@ class EngineMetrics:
 
     def __init__(self, kind: str = "serve"):
         scope = REGISTRY.scope(kind)
+        self._scope = scope
         self._c = scope.counters(*self.COUNTER_KEYS)
         self._wall = scope.counter("wall_s")
         self.admission_log: List[Any] = []
+        self.spans: Dict[str, Histogram] = {}
 
     # -- writes (engine-internal) ---------------------------------------------
     def inc(self, key: str, n: int = 1) -> None:
@@ -98,6 +104,12 @@ class EngineMetrics:
 
     def add_wall(self, dt: float) -> None:
         self._wall.inc(dt)
+
+    def span_hist(self, name: str) -> Histogram:
+        h = self.spans.get(name)
+        if h is None:
+            h = self.spans[name] = self._scope.histogram(f"{name}_ms")
+        return h
 
     def log_admission(self, rid: int) -> None:
         log = self.admission_log
@@ -178,8 +190,9 @@ class ServeEngine:
     ``tracer``: an optional ``repro.obs.TraceRecorder``; when attached the
     tick loop records each request's lifecycle spans (submit / stalls /
     prefill / tokens / finish). ``tracer=None`` (the default) skips every
-    hook — tracing costs nothing when off and <5% when on (serve_bench
-    asserts the bound).
+    hook. Either way the tick's own spans (``repro.obs.trace.SPANS``) time
+    into the engine's histograms and annotate the profiler's trace,
+    through the tracer's ``annotate`` where one is attached.
     """
 
     _kind = "serve"          # metrics-scope prefix + tracer tag family
@@ -195,8 +208,7 @@ class ServeEngine:
         self.tracer = tracer
         self._ttag = (tracer.register_engine(self._kind)
                       if tracer is not None else "")
-        self._annot = (tracer.annotate if tracer is not None
-                       else lambda name: contextlib.nullcontext())
+        self._annotate = tracer.annotate if tracer is not None else None
         self._enc_len = max(max_len // 4, 8)
         self._prefix = _stream_prefix(self.cfg)
 
@@ -233,7 +245,11 @@ class ServeEngine:
 
     # -- submission -----------------------------------------------------------
     def add_request(self, prompt: List[int], max_new_tokens: int = 16,
-                    adapter: Optional[str] = None) -> int:
+                    adapter: Optional[str] = None,
+                    t_arrival: Optional[float] = None) -> int:
+        """Queue a request. ``t_arrival`` (``perf_counter`` seconds) is when
+        it arrived, where the caller knows it: TTFT and latency then count
+        from there, so a wait before this call shows in them."""
         # validate eagerly: raises on a name neither resident nor in the
         # host store, or on naming an adapter when the runtime has no bank
         self.rt.validate_adapter(adapter)
@@ -241,7 +257,8 @@ class ServeEngine:
         rid = self._next_id
         self._next_id += 1
         req = Request(rid, list(prompt), max_new_tokens, adapter=adapter,
-                      t_submit=time.perf_counter())
+                      t_submit=(time.perf_counter() if t_arrival is None
+                                else t_arrival))
         self._queue.append(req)
         if self.tracer is not None:
             self.tracer.submit(self._ttag, rid, adapter=adapter,
@@ -301,6 +318,9 @@ class ServeEngine:
         return req.rid
 
     # -- internals ------------------------------------------------------------
+    def _span(self, name: str) -> span:
+        return span(name, self.stats.span_hist(name), self._annotate)
+
     def _bucket(self, plen: int) -> int:
         """Power-of-two prompt pad length (bounds prefill recompiles);
         clamped so prefix + bucket always fits the slot cache."""
@@ -355,11 +375,12 @@ class ServeEngine:
                                   ctx=self.rt.context([aid]))
             if self.tracer is not None:
                 self.tracer.prefill_start(self._ttag, req.rid)
-            with self._annot("prefill"):
+            with self._span("prefill"):
                 first, self._state = self._slot_prefill(
                     self.rt.params, feed, self._state,
                     jnp.asarray(slot, jnp.int32))
-            first = int(first)
+            with self._span("sync"):
+                first = int(first)
             req.t_first = time.perf_counter()
             if self.tracer is not None:
                 self.tracer.prefill_end(self._ttag, req.rid)
@@ -405,7 +426,7 @@ class ServeEngine:
         tokens = jnp.asarray(self._last[:, None])
         pos = jnp.asarray(self._pos)
         ctx = self._context()
-        with self._annot("decode"):
+        with self._span("decode"):
             nt, _, self._state = self._decode(self.rt.params, ctx, tokens,
                                               self._state, pos)
         self.stats.inc("decode_steps")
@@ -413,7 +434,8 @@ class ServeEngine:
 
     def _decode_commit(self, nt) -> None:
         """Sync the launched step's tokens and advance slot bookkeeping."""
-        vals = np.asarray(nt[:, 0])
+        with self._span("sync"):
+            vals = np.asarray(nt[:, 0])
         for slot in range(self.max_batch):
             if not self._row_active(slot):
                 continue
@@ -436,7 +458,14 @@ class ServeEngine:
         decode step, return the pending token array (None when no slot is
         decoding). Pass the result to ``step_commit`` — splitting the tick
         lets a multi-replica driver overlap every replica's device work."""
-        self._admit()
+        with self._span("launch"):
+            with self._span("admit"):
+                self._admit()
+            return self._dispatch()
+
+    def _dispatch(self):
+        """The tick's device work after admission: the decode step over
+        the active slots (None when none is decoding)."""
         if self.num_active:
             return self._decode_launch()
         return None
@@ -444,9 +473,10 @@ class ServeEngine:
     def step_commit(self, pending) -> bool:
         """Second half of ``step``: sync + bookkeep a launched tick.
         Returns True if any work remains queued or in flight."""
-        if pending is not None:
-            self._decode_commit(pending)
-        return not self.idle
+        with self._span("commit"):
+            if pending is not None:
+                self._decode_commit(pending)
+            return not self.idle
 
     def step(self) -> bool:
         """One scheduler tick: admit into free slots, then one decode step
@@ -468,6 +498,13 @@ class ServeEngine:
         latency, evictions, resident/padded bytes (None on eager banks)."""
         stats = getattr(self.rt.bank, "stats", None)
         return stats() if callable(stats) else None
+
+    def span_stats(self) -> Dict[str, Dict[str, float]]:
+        """``{span: {"count", "ms"}}`` of this engine's tick spans and its
+        store-paged bank's (``page_in`` and its ``sync``)."""
+        bank = getattr(self.rt.bank, "span_stats", None)
+        return merge_span_stats([span_stats(self.stats.spans)]
+                                + ([bank()] if bank is not None else []))
 
     def run(self) -> Dict[int, List[int]]:
         """Drain the queue to completion; returns {rid: tokens}."""
@@ -747,7 +784,7 @@ class PagedServeEngine(ServeEngine):
             ctx=self.rt.context([self._slot_ids[slot]]))
         if self.tracer is not None:                # span per prompt chunk
             self.tracer.prefill_start(self._ttag, req.rid)
-        with self._annot("prefill_chunk"):
+        with self._span("prefill_chunk"):
             first, _, self._state = self._chunk_prefill(
                 self.rt.params, feed, self._state,
                 jnp.asarray(slot, jnp.int32), jnp.asarray(start, jnp.int32))
@@ -758,7 +795,8 @@ class PagedServeEngine(ServeEngine):
             return
         self._prefill_q.popleft()
         self.pool.register(plan.sp)               # publish full prompt pages
-        first = int(first)
+        with self._span("sync"):
+            first = int(first)
         req.t_first = time.perf_counter()
         if self.tracer is not None:
             self.tracer.first_token(self._ttag, req.rid)
@@ -782,12 +820,10 @@ class PagedServeEngine(ServeEngine):
             self._state["table"].at[slot].set(self._zero_row)
         self.pool.finish(sp)
 
-    def step_launch(self):
-        """One tick's dispatch half: admit, feed ONE prompt chunk, launch
-        one decode step over the decoding slots. Decode latency is bounded
-        by one chunk of prefill per tick — never a whole prompt. (``step``
-        composes this with the inherited ``step_commit``.)"""
-        self._admit()
+    def _dispatch(self):
+        """After admission: feed ONE prompt chunk, launch one decode step
+        over the decoding slots. Decode latency is bounded by one chunk of
+        prefill per tick — never a whole prompt."""
         self._feed_one_chunk()
         if self._decoding.any():
             return self._decode_launch()

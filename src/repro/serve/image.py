@@ -76,19 +76,22 @@ class ImageServeEngine:
 
     # -- submission -----------------------------------------------------------
     def add_request(self, prompt, max_new_tokens: int = 1,
-                    adapter: Optional[str] = None) -> int:
+                    adapter: Optional[str] = None,
+                    t_arrival: Optional[float] = None) -> int:
         """Enqueue one image (the ``prompt`` field carries the (H, W, C)
         array — field names match the token engines so cluster routing and
         workload drivers need no image-specific casing); the response is a
         single class "token". ``max_new_tokens`` is accepted for surface
-        uniformity and ignored."""
+        uniformity and ignored. ``t_arrival`` as in
+        ``ServeEngine.add_request``."""
         del max_new_tokens
         self.rt.validate_adapter(adapter)
         img = _check_image(self.cfg, prompt)
         rid = self._next_id
         self._next_id += 1
         req = Request(rid, img, max_new_tokens=1, adapter=adapter,
-                      t_submit=time.perf_counter())
+                      t_submit=(time.perf_counter() if t_arrival is None
+                                else t_arrival))
         self._queue.append(req)
         if self.tracer is not None:
             self.tracer.submit(self._ttag, rid, adapter=adapter,

@@ -36,7 +36,6 @@ borrowing would resize a stack and retrace every jitted step.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -46,6 +45,7 @@ import numpy as np
 from repro.core import methods as methods_lib
 from repro.core import peft as peft_lib
 from repro.obs.metrics import REGISTRY
+from repro.obs.trace import span, span_stats
 
 from .store import AdapterStore
 
@@ -140,6 +140,7 @@ class PagedAdapterBank:
                                  "builds", "build_cache_hits")
         self._page_in_ms = scope.histogram("page_in_ms",
                                            cap=PAGE_IN_HIST_CAP)
+        self._sync_ms = scope.histogram("sync_ms")
         self._max_resident = scope.gauge("max_resident")
         # bumped on every residency change (page-in / evict): engines key
         # their per-step AdapterContext cache on (slot ids, version), so a
@@ -249,9 +250,8 @@ class PagedAdapterBank:
         # compact slot guarantees a free universal one
         uslot = self._free_universal.pop()
 
-        t0 = time.perf_counter()
-        self._page_in(name, method, cslot)
-        self._page_in_ms.observe((time.perf_counter() - t0) * 1e3)
+        with span("page_in", self._page_in_ms):
+            self._page_in(name, method, cslot)
         self._lut[method][uslot] = cslot
         self._resident[name] = (uslot, method, cslot)
         self._lru[name] = None
@@ -315,9 +315,10 @@ class PagedAdapterBank:
             for k in entry:
                 entry[k] = entry[k].at[idx].set(
                     jnp.asarray(page[k], entry[k].dtype))
-        jax.block_until_ready(
-            [self._stacks[p][method][k] for p, pg in pages.items()
-             for k in pg])
+        with span("sync", self._sync_ms):
+            jax.block_until_ready(
+                [self._stacks[p][method][k] for p, pg in pages.items()
+                 for k in pg])
 
     def place(self, sharding) -> None:
         """Commit every stack to ``sharding`` (a replica's own device or
@@ -354,10 +355,17 @@ class PagedAdapterBank:
         by the pre-obs short names (tests and tools read these)."""
         return {k: c.value for k, c in self._c.items()}
 
+    def span_stats(self) -> Dict[str, Dict[str, float]]:
+        """``{span: {"count", "ms"}}`` of the bank's ``page_in`` spans and
+        the device waits (``sync``) inside them."""
+        return span_stats({"page_in": self._page_in_ms,
+                           "sync": self._sync_ms})
+
     def stats(self) -> Dict[str, Any]:
         """Thin view over the bank's registry instruments — same keys the
         pre-obs dict exposed; page-in percentiles now come from the
-        bounded histogram."""
+        bounded histogram, and its exact count and sum read over a window
+        as deltas."""
         c = self.counters
         resident = self.resident_bytes()
         padded = self.padded_bytes()
@@ -377,6 +385,8 @@ class PagedAdapterBank:
             "build_cache_hits": c["build_cache_hits"],
             "page_in_ms_p50": self._page_in_ms.percentile(50),
             "page_in_ms_p95": self._page_in_ms.percentile(95),
+            "page_in_ms_sum": self._page_in_ms.sum,
+            "page_in_count": self._page_in_ms.count,
             "resident_bank_bytes": resident,
             "padded_bank_bytes": padded,
             "compaction_ratio": padded / resident if resident else 0.0,

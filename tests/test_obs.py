@@ -1,10 +1,13 @@
-"""Observability plane (ISSUE 10): typed-instrument registry semantics,
-per-request span lifecycle on real engine traffic, stall attribution,
-export formats, SLO thresholds/backpressure wiring, the bounded
-``page_in_ms`` histogram (regression for the unbounded-list leak), and
-the bench-regression gate's comparison logic."""
+"""Observability plane: typed-instrument registry semantics, per-request
+span lifecycle on real engine traffic, stall attribution, export formats,
+SLO thresholds/backpressure wiring, the bounded ``page_in_ms`` histogram
+(regression for the unbounded-list leak), the serving tick's spans and
+the per-span compile counter, TTFT from arrival, and the bench-regression
+gate's comparison logic."""
+import contextlib
 import io
 import json
+import time
 
 import pytest
 
@@ -14,14 +17,18 @@ np = pytest.importorskip("numpy")
 from repro.config import get_smoke_config               # noqa: E402
 from repro.core import peft as peft_lib                 # noqa: E402
 from repro.core.runtime import ModelRuntime             # noqa: E402
-from repro.launch.serve import make_demo_adapters       # noqa: E402
+from repro.distrib import EngineCluster                 # noqa: E402
+from repro.launch.serve import (                        # noqa: E402
+    drive_streaming, make_demo_adapters)
 from repro.obs import (                                 # noqa: E402
-    Counter, Gauge, Histogram, MetricsRegistry, RequestTrace, SLOMonitor,
-    TraceRecorder)
-from repro.serve.engine import ServeEngine              # noqa: E402
+    SPANS, Counter, Gauge, Histogram, MetricsRegistry, RequestTrace,
+    SLOMonitor, TraceRecorder, compile_counts, span)
+from repro.serve.engine import (                        # noqa: E402
+    PagedServeEngine, ServeEngine, latency_percentiles)
 from repro.store import AdapterStore                    # noqa: E402
 
 from benchmarks import check_regress                    # noqa: E402
+from benchmarks.common import mixed_workload, run_engine_timed  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -190,6 +197,154 @@ def test_export_formats(traced_run):
             assert ev["ts"] >= 0.0                  # relative to first submit
         if ev["ph"] == "X":
             assert ev["dur"] >= 0.0
+
+
+def test_span_completeness_warm_and_timed_passes(rt):
+    """One traced engine through the serving bench's protocol (a warm-up
+    pass, then the timed pass of the same mixed workload): every request
+    of both passes finishes with a complete span set, and the SLO report
+    carries TTFT and TPOT percentiles."""
+    tracer = TraceRecorder(slo=SLOMonitor(window=512),
+                           registry=MetricsRegistry())
+    workload = mixed_workload(8, 12, 8, seed=0)
+    run_engine_timed(lambda: ServeEngine(rt, max_batch=4, max_len=28,
+                                         eos_id=-1, tracer=tracer),
+                     workload, workload)
+    done = tracer.finished
+    assert len(done) == 2 * len(workload)
+    incomplete = [t.rid for t in done if not t.complete]
+    assert not incomplete, f"incomplete spans for rids {incomplete}"
+    rep = tracer.slo.report()
+    assert rep["ttft_ms"]["p95"] > 0 and rep["tpot_ms"]["p50"] > 0
+
+
+# -- TTFT from arrival ---------------------------------------------------------
+
+def test_ttft_counts_the_wait_before_admission(rt):
+    """A request the caller held for a while before ``add_request``
+    carries its arrival time: its TTFT and latency include the hold."""
+    tracer = TraceRecorder(registry=MetricsRegistry())
+    eng = ServeEngine(rt, max_batch=2, max_len=32, eos_id=-1, tracer=tracer)
+    held = 0.25
+    t_arrival = time.perf_counter() - held
+    eng.add_request([1, 2, 3, 4], max_new_tokens=3, t_arrival=t_arrival)
+    eng.run()
+    (tr,), (req,) = tracer.finished, eng.finished
+    assert tr.t_submit == req.t_submit == t_arrival
+    assert tr.ttft_s >= held + tr.prefill_s
+    assert latency_percentiles([req])[50] >= held
+
+
+def test_drive_streaming_submits_at_arrival_times(rt):
+    """``drive_streaming`` stamps each request with ``t0 + arrival``, not
+    with when a tick let it in: submit times are spaced as the arrivals
+    are, though the first tick (which compiles) holds the later two."""
+    tracer = TraceRecorder(registry=MetricsRegistry())
+    eng = ServeEngine(rt, max_batch=1, max_len=30, eos_id=-1, tracer=tracer)
+    arrivals = np.array([0.0, 0.001, 0.003])
+    drive_streaming(eng, [{"prompt": [5, 6, 7, 8], "max_new_tokens": 3}
+                          for _ in arrivals], arrivals)
+    trs = sorted(tracer.finished, key=lambda t: t.rid)
+    np.testing.assert_allclose(np.diff([t.t_submit for t in trs]),
+                               np.diff(arrivals), atol=1e-6)
+    # one slot: the second request waits for the first to finish
+    assert trs[1].ttft_s > trs[0].t_finish - trs[0].t_submit - 0.001
+
+
+# -- the serving tick's spans and the compile counter -------------------------
+
+def test_span_times_annotates_and_names_compiles():
+    """``span`` observes its block's milliseconds into the histogram, goes
+    through the given annotate hook (else a profiler annotation), and
+    counts an XLA compile under the innermost open span."""
+    outer, inner = Histogram("outer"), Histogram("inner")
+    names = []
+
+    def annotate(name):
+        names.append(name)
+        return contextlib.nullcontext()
+    before = compile_counts().get("inner", 0)
+    with span("outer", outer, annotate):
+        with span("inner", inner):
+            jax.jit(lambda x: x * 3.0 + 1.0)(np.ones(5, np.float32) * 7)
+            time.sleep(0.002)
+    assert names == ["outer"]
+    assert outer.count == inner.count == 1
+    assert outer.sum >= inner.sum >= 2.0
+    assert compile_counts()["inner"] > before
+
+
+def _drive(eng, requests):
+    """Submit ``requests`` and step to idle; returns the ticks run."""
+    for r in requests:
+        eng.add_request(**r)
+    ticks = 1
+    while eng.step():
+        ticks += 1
+    return ticks
+
+
+def test_tick_spans_on_paged_engine_with_paging_bank(rt):
+    """A paged engine over a store-paged bank smaller than its tenants:
+    every span of ``SPANS`` is recorded, one ``launch`` and one ``commit``
+    per tick, one ``sync`` per decode commit, final prompt chunk and
+    page-in, one ``page_in`` per bank miss that paged, and the host's
+    waits fit inside the tick's two halves."""
+    store, _ = _tenant_store(rt, n_ad=4)
+    eng = PagedServeEngine(rt.attach(store, hbm_budget=2), max_batch=2,
+                           max_len=40, eos_id=-1, page_size=4,
+                           prefill_chunk=8)
+    ticks = _drive(eng, [{"prompt": list(range(1, 12 + i)),
+                          "max_new_tokens": 3, "adapter": f"a{i % 4}"}
+                         for i in range(6)])
+    st = eng.span_stats()
+    assert set(SPANS) <= {n for n, s in st.items() if s["count"] > 0}
+    assert st["launch"]["count"] == st["commit"]["count"] == ticks
+    bank = eng.rt.bank.counters
+    page_ins = bank["misses"] - bank["stalls"]
+    assert page_ins > 0
+    assert st["page_in"]["count"] == page_ins
+    assert st["sync"]["count"] == (eng.stats["decode_steps"]
+                                   + eng.stats["prefills"] + page_ins)
+    assert st["sync"]["ms"] <= st["launch"]["ms"] + st["commit"]["ms"]
+    ad = eng.adapter_stats()
+    assert ad["page_in_count"] == page_ins
+    assert ad["page_in_ms_sum"] == pytest.approx(st["page_in"]["ms"])
+
+
+def test_cluster_span_stats_sum_replicas(rt):
+    store, _ = _tenant_store(rt, n_ad=2)
+    engines = [PagedServeEngine(rt.attach(store, hbm_budget=2), max_batch=2,
+                                max_len=40, eos_id=-1, page_size=4,
+                                prefill_chunk=8) for _ in range(2)]
+    cl = EngineCluster(engines)
+    _drive(cl, [{"prompt": [3, 4, 5, 6, 7], "max_new_tokens": 3,
+                 "adapter": f"a{i % 2}"} for i in range(4)])
+    per = [e.span_stats() for e in engines]
+    assert all(p["launch"]["count"] > 0 for p in per)
+    total = cl.span_stats()
+    for name in SPANS:
+        assert total[name]["count"] == sum(p[name]["count"] for p in per)
+        assert total[name]["ms"] == pytest.approx(
+            sum(p[name]["ms"] for p in per))
+    assert cl.adapter_stats()["page_in_count"] == total["page_in"]["count"]
+
+
+def test_first_decode_compiles_under_decode_and_repeats_do_not(rt):
+    """The compile counter names the step that compiled: the first decode
+    dispatch compiles under ``decode``; a tick identical to the one
+    before it compiles nothing anywhere."""
+    eng = PagedServeEngine(rt, max_batch=3, max_len=24, eos_id=-1,
+                           page_size=4, prefill_chunk=8)
+    eng.add_request([1, 2, 3, 4, 5], max_new_tokens=8)
+    before = compile_counts().get("decode", 0)
+    while eng.stats["decode_steps"] == 0:
+        eng.step()
+    assert compile_counts()["decode"] > before
+    eng.step()
+    settled = compile_counts()
+    eng.step()
+    assert compile_counts() == settled
 
 
 # -- stall attribution --------------------------------------------------------
