@@ -247,8 +247,10 @@ def gs_rotate_banked(entry: Params, ids: Array, x: Array,
     rotations serviceable per-request where a dense OFT rotation (O(d^2))
     would not be.
     """
-    L = jnp.take(entry["L"], ids, axis=0).astype(x.dtype)      # (B, r, b, b)
-    R = jnp.take(entry["R"], ids, axis=0).astype(x.dtype)
+    # ids are bank slots, always in range: "clip" lets the gather fuse with
+    # the cast, where the default "fill" adds a masking pass over the blocks
+    L = jnp.take(entry["L"], ids, axis=0, mode="clip").astype(x.dtype)
+    R = jnp.take(entry["R"], ids, axis=0, mode="clip").astype(x.dtype)
     return kernel_ops.gs_banked_transform_T(L, R, x, use_pallas=use_pallas)
 
 

@@ -11,6 +11,14 @@ sublanes, tokens saturate lanes.  One HBM read of x / one write of y total.
 
 VMEM per grid step:
     token_tile * group_tile * (bi + bo) * dtype  +  group_tile * bo * bi * 4
+
+Rows that carry few tokens and blocks of their own (per-request adapters at
+decode) leave that mapping's token tile nearly empty: vmapped over rows, it
+runs a grid of r / group_tile one-token steps per row, and the fixed cost
+of a grid step is the whole kernel. ``bdmm_rows_pallas`` takes all rows in
+one launch instead, with large steps (many rows x many blocks), and
+computes on the VPU: no block is shared between rows, so the MXU would
+reload weights for every row and token.
 """
 from __future__ import annotations
 
@@ -135,3 +143,61 @@ def bdmm_dblocks_pallas(dy: Array, x: Array, *, bo: int, bi: int,
         out_specs=pl.BlockSpec((group_tile, bo, bi), lambda gi, ti: (gi, 0, 0)),
         interpret=interpret,
     )(dy, x)
+
+
+def _bdmm_rows_kernel(x_ref, w_ref, o_ref, *, transpose: bool):
+    # x_ref (rt, T, gt, bi), w_ref (rt, gt, bo, bi) [(rt, gt, bi, bo) when
+    # transposed], o_ref (rt, T, gt, bo). Products widen to fp32 and are
+    # summed in fp32, then round once to the output dtype.
+    def row(i, carry):
+        w = w_ref[i].astype(jnp.float32)
+
+        def token(t, c):
+            x = x_ref[i, t].astype(jnp.float32)              # (gt, bi)
+            if transpose:
+                y = jnp.sum(w * x[:, :, None], axis=1)
+            else:
+                y = jnp.sum(w * x[:, None, :], axis=2)
+            o_ref[i, t] = y.astype(o_ref.dtype)
+            return c
+
+        return jax.lax.fori_loop(0, x_ref.shape[1], token, carry)
+
+    jax.lax.fori_loop(0, w_ref.shape[0], row, 0)
+
+
+def bdmm_rows_pallas(blocks: Array, x: Array, *, transpose: bool = False,
+                     row_tile: int = 16, group_tile: int = 32,
+                     interpret: bool = False) -> Array:
+    """Per-row block-diagonal matmul, all rows in one launch.
+
+    blocks: (B, r, bo, bi), or (B, r, bi, bo) with ``transpose`` (the blocks'
+    other axis is contracted, so a caller applying B^T passes B as it is);
+    x: (B, T, r*bi) -> (B, T, r*bo). Row i uses its own blocks[i].
+
+    The kernel sees x as (B, T, r, bi), reshaped by XLA, so no lane split
+    happens inside it. The grid runs over (row tiles, group tiles); the last
+    tile of either may overhang, and its padding only reaches outputs that
+    are dropped (every output reads its own block and input group alone).
+    """
+    bsz, r, b1, b2 = blocks.shape
+    bo, bi = (b2, b1) if transpose else (b1, b2)
+    t = x.shape[1]
+    assert x.shape == (bsz, t, r * bi), (blocks.shape, x.shape, transpose)
+    rt = min(row_tile, bsz)
+    # the group tile is x's second-minor block dim: a multiple of 16 (a
+    # bf16 sublane tile) or all of r
+    gt = r if r <= group_tile else max(16, group_tile // 16 * 16)
+    out = pl.pallas_call(
+        functools.partial(_bdmm_rows_kernel, transpose=transpose),
+        out_shape=jax.ShapeDtypeStruct((bsz, t, r, bo), x.dtype),
+        grid=(pl.cdiv(bsz, rt), pl.cdiv(r, gt)),
+        in_specs=[
+            pl.BlockSpec((rt, t, gt, bi), lambda i, g: (i, 0, g, 0)),
+            pl.BlockSpec((rt, gt, b1, b2), lambda i, g: (i, g, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((rt, t, gt, bo), lambda i, g: (i, 0, g, 0)),
+        interpret=interpret,
+        name="bdmm_rows",
+    )(x.reshape(bsz, t, r, bi), blocks)
+    return out.reshape(bsz, t, r * bo)

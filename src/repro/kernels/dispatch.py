@@ -23,6 +23,10 @@ This module is the single place that decides *how* a GS kernel call runs:
         gs_T:  dx = Q dy (the forward kernel); dL/dR via the identity
                <g, Q^T x> = <x, Q g>, i.e. the same factor-gradient kernels
                with (input, cotangent) swapped.
+        gs_T_rows: the row-batched banked forward; its backward is gs_T's,
+               per row.
+  * ``banked_gs_rows_tiles`` — which kernel a banked GS rotation takes,
+    from the tokens per row.
 
 Autotuning is *eager* (it times real kernel launches), so call it from
 benchmarks / warmup code, never inside jit; lookups inside jit are pure
@@ -41,8 +45,9 @@ import jax
 import jax.numpy as jnp
 
 from .bdmm import bdmm_dblocks_pallas, bdmm_pallas, default_group_tile
-from .gs_fused import (gs_fused_T_pallas, gs_fused_bwd_pallas,
-                       gs_fused_grads_pallas, gs_fused_pallas)
+from .gs_fused import (gs_fused_T_pallas, gs_fused_T_rows_pallas,
+                       gs_fused_bwd_pallas, gs_fused_grads_pallas,
+                       gs_fused_pallas)
 from .q_matmul import default_n_tile, gs_q_matmul_pallas, q_matmul_pallas
 
 Array = jnp.ndarray
@@ -152,8 +157,10 @@ def paged_attn_key(h: int, kh: int, d: int, page: int, dtype,
 # their launch geometry through these key families — keyed by KERNEL name
 # (this module's vocabulary); WHICH family an adapter method rides is that
 # method's ``MethodOps.banked_kernel`` field in core.methods (single
-# source of per-method truth). Today: the gsoft bank rides the vmapped
-# gs_T kernel ("gs"), oft/boft banks ride the vmapped bdmm kernel ("bdmm",
+# source of per-method truth). Today: the gsoft bank rides the gs_T kernel
+# ("gs"; vmapped per row, or row-batched at decode, which tunes nothing
+# here: ``banked_gs_rows_tiles``), oft/boft banks ride the vmapped bdmm
+# kernel ("bdmm",
 # one bdmm per butterfly level for boft), and householder declares no
 # kernel — its banked transform is an O(k*d)-per-token reference einsum
 # (kernels/ref.py), so there is nothing to tune.
@@ -306,6 +313,37 @@ def _ensure_cache_loaded() -> None:
 def _write_through() -> None:
     if os.environ.get(TUNING_CACHE_ENV):
         save_tuning_cache()
+
+
+# Banked GS rotations (one adapter per row) take one of two kernels, chosen
+# by the input's shape. The per-row path vmaps the token-major ``gs_T``
+# kernel: each row gets its own grid of cdiv(T, token_tile) x r/group_tile
+# steps, and at decode (T = 1) every step holds one token and a few blocks,
+# so the launch is all per-step overhead. Below ROWS_MAX_TOKENS tokens per
+# row (the crossover on a v5e at d 8192, b 32, 32 rows: the row-batched
+# rotation took 0.66 / 0.90 / 1.30 / 2.09 ms at 1 / 2 / 4 / 8 tokens, the
+# per-row one 1.55-1.61 ms at each), the row-batched kernel takes all rows
+# in one launch with large steps:
+# ROWS_GROUP_TILE blocks of the most rows (a power of two) whose blocks fit
+# ROWS_STEP_VMEM, a (b, b) block taking whole (sublane, 128-lane) tiles in
+# VMEM: 16 rows of 32 blocks of 32 x 32 bf16, 1 MB of blocks per step.
+ROWS_MAX_TOKENS = 5
+ROWS_GROUP_TILE = 32
+ROWS_STEP_VMEM = 4 << 20
+
+
+def banked_gs_rows_tiles(tokens: int, rows: int, r: int, b: int,
+                         itemsize: int) -> Optional[Tuple[int, int]]:
+    """(row_tile, group_tile) of the row-batched banked GS kernel for
+    ``rows`` rows of ``tokens`` tokens over r blocks of b x b, or None where
+    the per-row path runs (enough tokens per row to fill its token tiles)."""
+    if tokens >= ROWS_MAX_TOKENS:
+        return None
+    sublanes = 32 // itemsize
+    block = (-(-b // sublanes) * sublanes) * (-(-b // 128) * 128) * itemsize
+    group_tile = min(r, ROWS_GROUP_TILE)
+    fit = max(1, ROWS_STEP_VMEM // (group_tile * block))
+    return min(rows, 1 << (fit.bit_length() - 1)), group_tile
 
 
 def pick_chunk(t: int, chunk: int) -> int:
@@ -500,3 +538,27 @@ def _gs_T_bwd(tuning, interpret, res, dy):
 
 
 gs_T_diff.defvjp(_gs_T_fwd, _gs_T_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def gs_T_rows_diff(row_tile: int, group_tile: int, interpret: bool, L: Array,
+                   R: Array, x: Array) -> Array:
+    """Differentiable per-row transpose rotation for rows of few tokens:
+    L, R (B, r, b, b), x (B, T, d). The backward runs the per-row kernels
+    of ``gs_T_diff``."""
+    return gs_fused_T_rows_pallas(L, R, x, row_tile=row_tile,
+                                  group_tile=group_tile, interpret=interpret)
+
+
+def _gs_T_rows_fwd(row_tile, group_tile, interpret, L, R, x):
+    y = gs_T_rows_diff(row_tile, group_tile, interpret, L, R, x)
+    return y, (L, R, x)
+
+
+def _gs_T_rows_bwd(row_tile, group_tile, interpret, res, dy):
+    L, R, x = res
+    bwd = functools.partial(_gs_T_bwd, Tuning(), interpret)
+    return jax.vmap(lambda l, rr, xx, g: bwd((l, rr, xx), g))(L, R, x, dy)
+
+
+gs_T_rows_diff.defvjp(_gs_T_rows_fwd, _gs_T_rows_bwd)

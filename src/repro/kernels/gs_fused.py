@@ -7,6 +7,8 @@ reshape + transpose of the (T, d) activation that XLA performs between the
 launches. The shuffle is kept out of the kernel bodies on purpose: inside
 a kernel it splits the lane dimension, (T, r*b) -> (T, r, b) with b < 128,
 and Mosaic refuses that shape cast at real widths (d 8192, b 32).
+``gs_fused_T_rows_pallas`` is the per-row (banked) transpose rotation for
+rows of few tokens: each stage is one ``bdmm_rows`` launch over all rows.
 
 Intermediates round to ``x.dtype`` between stages, exactly as the oracle
 ``ref.gs_fused_ref`` does. The backward pass is built from the same two
@@ -19,21 +21,21 @@ import functools
 
 import jax.numpy as jnp
 
-from .bdmm import bdmm_dblocks_pallas, bdmm_pallas
+from .bdmm import bdmm_dblocks_pallas, bdmm_pallas, bdmm_rows_pallas
 
 Array = jnp.ndarray
 
 
 def _shuffle(y: Array, r: int, b: int) -> Array:
     """P (k = r): flat feature g*b + i moves to i*r + g."""
-    t = y.shape[0]
-    return y.reshape(t, r, b).swapaxes(1, 2).reshape(t, r * b)
+    lead = y.shape[:-1]
+    return y.reshape(lead + (r, b)).swapaxes(-1, -2).reshape(lead + (r * b,))
 
 
 def _unshuffle(y: Array, r: int, b: int) -> Array:
     """P^T (k = b): the inverse of ``_shuffle``."""
-    t = y.shape[0]
-    return y.reshape(t, b, r).swapaxes(1, 2).reshape(t, r * b)
+    lead = y.shape[:-1]
+    return y.reshape(lead + (b, r)).swapaxes(-1, -2).reshape(lead + (r * b,))
 
 
 def _bd(token_tile: int, interpret: bool):
@@ -62,6 +64,22 @@ def gs_fused_T_pallas(L: Array, R: Array, x: Array, *, token_tile: int = 128,
     bd = _bd(token_tile, interpret)
     y = _unshuffle(bd(jnp.swapaxes(L, -1, -2), _shuffle(x, r, b)), r, b)
     return bd(jnp.swapaxes(R, -1, -2), y)
+
+
+def gs_fused_T_rows_pallas(L: Array, R: Array, x: Array, *,
+                           row_tile: int = 16, group_tile: int = 32,
+                           interpret: bool = False) -> Array:
+    """Per-row transpose rotation  y[i] = R_i^T P^T L_i^T P x[i]  for rows of
+    few tokens: L, R (B, r, b, b) as gathered, x (B, T, d=r*b).
+
+    Each stage is one ``bdmm_rows`` launch over all rows that contracts the
+    blocks' first axis, so L^T and R^T are never materialised.
+    """
+    r, b = L.shape[1], L.shape[2]
+    bd = functools.partial(bdmm_rows_pallas, transpose=True,
+                           row_tile=row_tile, group_tile=group_tile,
+                           interpret=interpret)
+    return bd(R, _unshuffle(bd(L, _shuffle(x, r, b)), r, b))
 
 
 def _gs_bwd(L: Array, R: Array, x: Array, dy: Array, *, token_tile: int,

@@ -25,6 +25,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..obs.metrics import REGISTRY
 from . import dispatch, ref
 from .dispatch import Tuning, interpret_mode
 from .flash_attention import flash_attention, paged_flash_decode
@@ -108,11 +109,24 @@ def gs_banked_transform_T(L: Array, R: Array, x: Array,
     L, R: (B, r, b, b) pre-gathered per-row orthogonal blocks; x: (B, T, d).
     This is the continuous-batching engine's multi-adapter hot path: each
     decode slot rotates its activations with its own adapter at O(b*d) per
-    token instead of re-merging an O(d^2) weight set per request."""
+    token instead of re-merging an O(d^2) weight set per request.
+
+    On the Pallas path the shape picks the kernel
+    (``dispatch.banked_gs_rows_tiles``): rows of few tokens (decode) take
+    the row-batched kernel, one launch over all rows per stage; longer rows
+    (prompt chunks, image lanes) vmap the per-row ``gs_T`` kernel, whose
+    geometry ``tuning`` pins. Each lowering counts its path in the metrics
+    registry (``gs_banked_path.rows`` / ``gs_banked_path.per_row``)."""
     if use_pallas:
-        _, r, b, _bb = L.shape
-        tun = tuning or dispatch.get_tuning(dispatch.gs_key(r, b, x.dtype))
+        bsz, r, b, _bb = L.shape
         interp = interpret_mode()
+        tiles = dispatch.banked_gs_rows_tiles(x.shape[1], bsz, r, b,
+                                              L.dtype.itemsize)
+        if tiles is not None:
+            REGISTRY.counter("gs_banked_path.rows").inc()
+            return dispatch.gs_T_rows_diff(*tiles, interp, L, R, x)
+        REGISTRY.counter("gs_banked_path.per_row").inc()
+        tun = tuning or dispatch.get_tuning(dispatch.gs_key(r, b, x.dtype))
         return jax.vmap(
             lambda l, rr, xx: dispatch.gs_T_diff(tun, interp, l, rr, xx))(
                 L, R, x)

@@ -53,7 +53,7 @@ from repro.distrib import EngineCluster, format_cluster_report, serve_mesh
 from repro.launch import compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import registry
-from repro.obs import SLOMonitor, TraceRecorder, compile_counts
+from repro.obs import REGISTRY, SLOMonitor, TraceRecorder, compile_counts
 from repro.serve.engine import (PagedServeEngine, ServeEngine,
                                 StaticServeEngine, latency_percentiles)
 from repro.serve.image import ImageServeEngine
@@ -463,6 +463,13 @@ def main():
     compiles = compile_counts()
     print("compiles by span: " + " ".join(
         f"{k}={v}" for k, v in sorted(compiles.items())))
+    # which banked GS kernel each lowering took (decode: rows; chunks,
+    # images: per_row)
+    gs_paths = {k.split(".", 1)[1]: v for k, v in
+                REGISTRY.snapshot("gs_banked_path.").items()}
+    if gs_paths:
+        print("banked GS paths (lowerings): " + " ".join(
+            f"{k}={v}" for k, v in sorted(gs_paths.items())))
     if isinstance(eng, EngineCluster):
         # the ONE residency/routing report — replica rows carry the bank
         # and KV-pool residency that used to be printed ad hoc here
@@ -479,6 +486,7 @@ def main():
             "prefills": eng.stats["prefills"],
             "admission_stalls": eng.stats["admission_stalls"],
             "wall_s": round(dt, 6), "compiles": compiles,
+            "gs_banked_paths": gs_paths,
             "slo": slo.report() if slo is not None else None}))
     if tracer is not None and args.trace_out:
         if args.trace_out.endswith(".jsonl"):

@@ -11,6 +11,7 @@ and take its interpret branch.
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU compiler library.
 """
+import functools
 import os
 
 import jax
@@ -18,9 +19,10 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.bdmm import bdmm_pallas
+from repro.kernels.dispatch import banked_gs_rows_tiles
 from repro.kernels.flash_attention import paged_flash_decode
-from repro.kernels.gs_fused import (gs_fused_T_pallas, gs_fused_bwd_pallas,
-                                    gs_fused_pallas)
+from repro.kernels.gs_fused import (gs_fused_T_pallas, gs_fused_T_rows_pallas,
+                                    gs_fused_bwd_pallas, gs_fused_pallas)
 from repro.kernels.q_matmul import gs_q_matmul_pallas, q_matmul_pallas
 
 D, FF, B = 8192, 29568, 32
@@ -110,6 +112,18 @@ def test_gs_banked_T_decode_compiles(one_chip):
     _compile(jax.vmap(gs_fused_T_pallas), one_chip,
              ((8, D // B, B, B), BF16), ((8, D // B, B, B), BF16),
              ((8, 1, D), BF16))
+
+
+@pytest.mark.parametrize("d", [D, FF])
+def test_gs_banked_T_rows_decode_compiles(one_chip, d):
+    """The row-batched banked rotation at decode: 32 rows of one token, in
+    the tiles the dispatch rule picks (d_ff's r = 924 overhangs them)."""
+    rows, r = 32, d // B
+    row_tile, group_tile = banked_gs_rows_tiles(1, rows, r, B, 2)
+    fn = functools.partial(gs_fused_T_rows_pallas, row_tile=row_tile,
+                           group_tile=group_tile)
+    _compile(fn, one_chip, ((rows, r, B, B), BF16), ((rows, r, B, B), BF16),
+             ((rows, 1, d), BF16))
 
 
 def test_paged_flash_decode_compiles(one_chip):
